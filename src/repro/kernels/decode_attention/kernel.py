@@ -46,14 +46,14 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, qpos_ref, o_ref,
     q = q_ref[0].astype(jnp.float32)                  # (R, hd)
     k = k_ref[0, 0].astype(jnp.float32)               # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    pos = pos_ref[0]                                  # (bk,)
-    q_pos = qpos_ref[0]                               # (R,) per-row position
+    pos = pos_ref[0, 0]                               # (1, bk) lane-dense
+    q_pos = qpos_ref[0]                               # (R, 1) per-row position
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    valid = (pos[None, :] >= 0) & (pos[None, :] <= q_pos[:, None])
+    valid = (pos >= 0) & (pos <= q_pos)
     if window:
-        valid &= pos[None, :] > (q_pos[:, None] - window)
+        valid &= pos > (q_pos - window)
     s = jnp.where(valid, s, NEG_INF)                  # (R, bk)
 
     m_prev = m_scr[:, 0:1]
@@ -87,6 +87,13 @@ def _paged_decode_kernel(bt_ref, q_ref, k_ref, v_ref, pos_ref, qpos_ref,
                    bk=bk, R=R)
 
 
+def _row_positions(q_pos, G):
+    """(B, T) query positions -> (B, T·G, 1): one position per MXU row
+    (row r is query token r // G), as a column the (R, bk) mask
+    broadcasts against."""
+    return jnp.repeat(q_pos.astype(jnp.int32), G, axis=1)[..., None]
+
+
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, pos, q_pos,
                                   *, window=0, interpret=False):
     """Paged variant: identical online-softmax body, but the KV grid
@@ -113,9 +120,10 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, pos, q_pos,
           .reshape(B * Hkv, R, hd)
     kg = jnp.transpose(k_pool, (2, 0, 1, 3))          # (Hkv, P+1, ps, hd)
     vg = jnp.transpose(v_pool, (2, 0, 1, 3))
-    posg = jnp.repeat(pos, Hkv, axis=0)               # (B*Hkv, S)
-    qpos_r = jnp.repeat(q_pos.astype(jnp.int32), G, axis=1)   # (B, R)
-    qposg = jnp.repeat(qpos_r, Hkv, axis=0)           # (B*Hkv, R)
+    # one lane-dense row of positions per logical block; the grid's
+    # (sequence, kv head) index reads its sequence's rows (b // Hkv)
+    posb = pos.reshape(B, NB, 1, ps)
+    qposr = _row_positions(q_pos, G)                  # (B, R, 1)
 
     grid = (B * Hkv, 1, NB)
     kernel = functools.partial(_paged_decode_kernel, scale=1.0 / (hd ** 0.5),
@@ -133,8 +141,9 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, pos, q_pos,
                          lambda b, h, j, bt: (b % Hkv, bt[b // Hkv, j], 0, 0)),
             pl.BlockSpec((1, 1, ps, hd),
                          lambda b, h, j, bt: (b % Hkv, bt[b // Hkv, j], 0, 0)),
-            pl.BlockSpec((1, ps), lambda b, h, j, bt: (b, j)),
-            pl.BlockSpec((1, R), lambda b, h, j, bt: (b, 0)),
+            pl.BlockSpec((1, 1, 1, ps),
+                         lambda b, h, j, bt: (b // Hkv, j, 0, 0)),
+            pl.BlockSpec((1, R, 1), lambda b, h, j, bt: (b // Hkv, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, R, hd), lambda b, h, j, bt: (b, 0, 0)),
         scratch_shapes=[
@@ -148,7 +157,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_table, pos, q_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hkv, R, hd), q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), qg, kg, vg, posg, qposg)
+    )(block_table.astype(jnp.int32), qg, kg, vg, posb, qposr)
     out = out.reshape(B, Hkv, T, G, hd).transpose(0, 2, 1, 3, 4) \
              .reshape(B, T, Hq, hd)
     return out[:, 0] if squeeze else out
@@ -173,9 +182,9 @@ def decode_attention_pallas(q, k, v, pos, q_pos, *, window=0, bk=128,
           .reshape(B * Hkv, R, hd)
     kg = k.reshape(B * Hkv, 1, S, hd)
     vg = v.reshape(B * Hkv, 1, S, hd)
-    posg = jnp.repeat(pos, Hkv, axis=0)               # (B*Hkv, S)
-    qpos_r = jnp.repeat(q_pos.astype(jnp.int32), G, axis=1)   # (B, R)
-    qposg = jnp.repeat(qpos_r, Hkv, axis=0)           # (B*Hkv, R)
+    # positions stay per sequence: grid index b reads row b // Hkv
+    posr = pos[:, None, :]                            # (B, 1, S)
+    qposr = _row_positions(q_pos, G)                  # (B, R, 1)
 
     grid = (B * Hkv, 1, S // bk)
     kernel = functools.partial(_decode_kernel, scale=1.0 / (hd ** 0.5),
@@ -187,8 +196,8 @@ def decode_attention_pallas(q, k, v, pos, q_pos, *, window=0, bk=128,
             pl.BlockSpec((1, R, hd), lambda b, h, j: (b, 0, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, R), lambda b, h, j: (b, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, j: (b // Hkv, 0, j)),
+            pl.BlockSpec((1, R, 1), lambda b, h, j: (b // Hkv, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, R, hd), lambda b, h, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, R, hd), q.dtype),
@@ -198,7 +207,7 @@ def decode_attention_pallas(q, k, v, pos, q_pos, *, window=0, bk=128,
             pltpu.VMEM((R, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, kg, vg, posg, qposg)
+    )(qg, kg, vg, posr, qposr)
     out = out.reshape(B, Hkv, T, G, hd).transpose(0, 2, 1, 3, 4) \
              .reshape(B, T, Hq, hd)
     return out[:, 0] if squeeze else out

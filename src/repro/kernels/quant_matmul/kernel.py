@@ -22,8 +22,8 @@ def _qmm_int8_kernel(x_ref, q_ref, s_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)                 # (bm, K)
     q = q_ref[...].astype(jnp.float32)                 # (K, bn)
     acc = jnp.dot(x, q, preferred_element_type=jnp.float32)
-    s = s_ref[...].astype(jnp.float32)                 # (bn,)
-    o_ref[...] = (acc * s[None, :]).astype(o_ref.dtype)
+    s = s_ref[...].astype(jnp.float32)                 # (1, bn)
+    o_ref[...] = (acc * s).astype(o_ref.dtype)
 
 
 def _qmm_int4_kernel(x_ref, p_ref, s_ref, o_ref, *, group_size: int):
@@ -55,12 +55,12 @@ def quant_matmul_int8_pallas(x, q, scale, *, bm=128, bn=128,
         in_specs=[
             pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
             pl.BlockSpec((K, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
-    )(x, q, scale)
+    )(x, q, scale.reshape(1, N))
 
 
 def quant_matmul_int4_pallas(x, q4, scale, *, bm=128, bn=128,

@@ -6,13 +6,23 @@ TPU adaptation of the SSD algorithm (arXiv:2405.21060):
   steps — the TPU analogue of the GPU kernel's persistent-CTA carry.
 * All four inner products are expressed as (chunk x n/p) matmuls so the
   quadratic *dual* form runs on the MXU; with chunk/p/n multiples of 128
-  every matmul is systolic-aligned. The elementwise decay algebra runs on
-  the VPU in fp32.
+  every matmul is systolic-aligned. ``B`` arrives transposed and the
+  carried state is kept as (n, p), so every matmul is a plain
+  (M, K) @ (K, N) product. The elementwise decay algebra runs on the VPU
+  in fp32.
 * One (batch, head) pair per grid row keeps the working set
   (4·chunk·max(p,n) fp32) comfortably inside VMEM.
+* Layouts the TPU compiler accepts: per-position scalars (``dt``) arrive
+  as lane-dense ``(1, chunk)`` rows, and the per-head scalars ``A``/``D``
+  sit whole in SMEM, read at the head's grid index. Mosaic has no
+  cumsum, so the in-chunk prefix sums are masked reductions, and the
+  row <-> column flips the decay algebra needs are exact diagonal selects
+  followed by a reduction (one nonzero term per sum). No value is ever a
+  (1, 1) vector: Mosaic cannot broadcast one along sublanes and lanes at
+  once, so every broadcast runs along one axis only.
 
 Outputs y per position and the final state (for prefill -> decode
-handoff), exactly matching ``ref.ssd_reference``.
+handoff), matching ``ref.ssd_reference``.
 """
 from __future__ import annotations
 
@@ -23,9 +33,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _eye(n: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
+def _to_col(row, eye):
+    """(1, n) row -> (n, 1) column, exactly."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _to_row(col, eye):
+    """(n, 1) column -> (1, n) row, exactly."""
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
 
 def _ssd_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref,
                 y_ref, state_ref, s_scr, *, chunk: int):
+    hi = pl.program_id(1)
     c = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -34,39 +62,42 @@ def _ssd_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref,
         s_scr[...] = jnp.zeros_like(s_scr)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)       # (chunk, p)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)     # (chunk,)
-    A = A_ref[0].astype(jnp.float32)             # scalar
-    Bm = B_ref[0, 0, 0].astype(jnp.float32)      # (chunk, n)
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)     # (1, chunk)
+    A = A_ref[hi]                                # scalar (SMEM)
+    Bt = B_ref[0, 0, 0].astype(jnp.float32)      # (n, chunk)
     Cm = C_ref[0, 0, 0].astype(jnp.float32)      # (chunk, n)
-    D = D_ref[0].astype(jnp.float32)
+    D = D_ref[hi]
+    p = x.shape[1]
 
-    dA = dt * A                                  # (chunk,)
-    cum = jnp.cumsum(dA)                         # inclusive
-    # L[i, j] = exp(cum_i - cum_j) for j <= i else 0
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    Lmat = jnp.where(lj <= li, jnp.exp(cum[:, None] - cum[None, :]), 0.0)
+    eye = li == lj
+    causal = lj <= li
+    dA = dt * A                                  # (1, chunk)
+    # inclusive prefix sum, as a column: cum[i] = sum_{j<=i} dt_j·A
+    cum_c = jnp.sum(jnp.where(causal, dA, 0.0), axis=1, keepdims=True)
+    cum_r = _to_row(cum_c, eye)                  # (1, chunk)
+    dt_c = _to_col(dt, eye)                      # (chunk, 1)
+    # decay from each position to the chunk end: sum_{j>i} dt_j·A
+    rest_c = jnp.sum(jnp.where(lj > li, dA, 0.0), axis=1, keepdims=True)
+    # whole-chunk decay, as a row across the (n, p) state's lanes
+    total = jnp.sum(jnp.broadcast_to(dt_c * A, (chunk, p)), axis=0,
+                    keepdims=True)               # (1, p)
+    # L[i, j] = exp(cum_i - cum_j) for j <= i else 0
+    Lmat = jnp.where(causal, jnp.exp(cum_c - cum_r), 0.0)
 
-    xdt = x * dt[:, None]                        # (chunk, p)
+    xdt = x * dt_c                               # (chunk, p)
+    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
     # intra-chunk dual form: (C B^T ⊙ L) @ (dt·x)
-    scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    y = jax.lax.dot_general(scores * Lmat, xdt, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    y = dot(dot(Cm, Bt) * Lmat, xdt)
     # inter-chunk: contribution of the carried state
-    state = s_scr[...]                           # (p, n)
-    y_off = jax.lax.dot_general(Cm, state, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    y = y + y_off * jnp.exp(cum)[:, None]
+    state = s_scr[...]                           # (n, p)
+    y = y + dot(Cm, state) * jnp.exp(cum_c)
     y = y + x * D
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
-    # state update: s' = exp(cum_end)·s + Σ_j exp(cum_end - cum_j)·dt_j x_j B_j^T
-    decay = jnp.exp(cum[-1] - cum)               # (chunk,)
-    upd = jax.lax.dot_general(xdt * decay[:, None], Bm,
-                              (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    s_scr[...] = state * jnp.exp(cum[-1]) + upd
+    # state update: s' = exp(cum_end)·s + Σ_j exp(cum_end - cum_j)·dt_j B_j x_j^T
+    s_scr[...] = state * jnp.exp(total) + dot(Bt, xdt * jnp.exp(rest_c))
 
     @pl.when(c == nc - 1)
     def _emit_state():
@@ -74,7 +105,8 @@ def _ssd_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref,
 
 
 def _ssd_extend_kernel(s0_ref, x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref,
-                       y_ref, state_ref, s_scr):
+                       y_ref, state_ref, s_scr, *, tb: int):
+    hi = pl.program_id(1)
     t = pl.program_id(2)
     nt = pl.num_programs(2)
 
@@ -82,63 +114,83 @@ def _ssd_extend_kernel(s0_ref, x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref,
     def _init():
         s_scr[...] = s0_ref[0, 0].astype(jnp.float32)
 
-    x = x_ref[0, 0].astype(jnp.float32)          # (1, p)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)     # scalar
-    A = A_ref[0].astype(jnp.float32)
-    Bv = B_ref[0, 0].astype(jnp.float32)         # (1, n)
-    Cv = C_ref[0, 0].astype(jnp.float32)         # (1, n)
-    D = D_ref[0].astype(jnp.float32)
+    A = A_ref[hi]
+    D = D_ref[hi]
+    p, n = s_scr.shape
+    eye = _eye(p)
 
-    # one ssd_decode_step, bitwise: s' = exp(dt·A)·s + (dt·x) B^T,
-    # y = C s'^T (+ D·x)
-    dA = jnp.exp(dt * A)
-    xdt = x * dt                                 # (1, p)
-    upd = jax.lax.dot_general(xdt, Bv, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    s = s_scr[...] * dA + upd                    # (p, n)
-    y = jax.lax.dot_general(Cv, s, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    y_ref[0, 0] = (y + x * D).astype(y_ref.dtype)
-    s_scr[...] = s
+    def token(i, s):
+        # one ssd_decode_step: s' = exp(dt·A)·s + (dt·x) B^T,
+        # y = C s'^T (+ D·x)
+        x = x_ref[0, 0, pl.ds(i, 1), :].astype(jnp.float32)    # (1, p)
+        Bv = B_ref[0, 0, pl.ds(i, 1), :].astype(jnp.float32)   # (1, n)
+        Cv = C_ref[0, 0, pl.ds(i, 1), :].astype(jnp.float32)
+        dt_i = dt_ref[0, 0, pl.ds(i, 1), :]      # (1, 128): dt_i per lane
+        xdt = x * dt_i[:, :p]
+        s = s * jnp.exp(dt_i[:, :n] * A) + _to_col(xdt, eye) * Bv
+        y = _to_row(jnp.sum(s * Cv, axis=1, keepdims=True), eye)
+        y_ref[0, 0, pl.ds(i, 1), :] = (y + x * D).astype(y_ref.dtype)
+        return s
+
+    s_scr[...] = jax.lax.fori_loop(0, tb, token, s_scr[...])
 
     @pl.when(t == nt - 1)
     def _emit_state():
         state_ref[0, 0] = s_scr[...]
 
 
+_DT_LANES = 128
+
+
+def _token_block(T: int) -> int:
+    """Tokens per grid step: a divisor of T that is a multiple of 8, else
+    all of T (a block's second-minor dim must be a multiple of 8 or the
+    full axis)."""
+    for tb in (256, 128):
+        if T % tb == 0:
+            return tb
+    return T
+
+
 def ssd_extend_pallas(state, x, dt, A, B, C, D=None, *, interpret=False):
     """Same contract as ``ref.ssd_extend_reference``: multi-token
-    sequential recurrence from an explicit initial state. The token axis
-    is the sequential grid dimension; the (p, n) state lives in VMEM
-    scratch across grid steps, seeded from ``state`` at t == 0."""
+    sequential recurrence from an explicit initial state. Each grid step
+    walks a block of tokens in order with the (p, n) state carried in
+    VMEM scratch, seeded from ``state`` at the first block. Each token's
+    ``dt`` arrives repeated across a row of lanes, so the loop reads it
+    with the same one-row slice as its ``x``/``B``/``C`` (p, n <= 128)."""
     b, T, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
     rep = h // g
     if D is None:
         D = jnp.zeros((h,), jnp.float32)
+    assert max(p, n) <= _DT_LANES, (p, n)
+    tb = _token_block(T)
 
     xk = x.transpose(0, 2, 1, 3)                 # (b, h, T, p)
-    dtk = dt.transpose(0, 2, 1)                  # (b, h, T)
+    dtk = jnp.broadcast_to(dt.transpose(0, 2, 1)[..., None].astype(
+        jnp.float32), (b, h, T, _DT_LANES))      # (b, h, T, lanes)
     Bk = B.transpose(0, 2, 1, 3)                 # (b, g, T, n)
     Ck = C.transpose(0, 2, 1, 3)
 
-    grid = (b, h, T)
+    grid = (b, h, T // tb)
     y, final = pl.pallas_call(
-        _ssd_extend_kernel,
+        functools.partial(_ssd_extend_kernel, tb=tb),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ti: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, 1, p), lambda bi, hi, ti: (bi, hi, ti, 0)),
-            pl.BlockSpec((1, 1, 1), lambda bi, hi, ti: (bi, hi, ti)),
-            pl.BlockSpec((1,), lambda bi, hi, ti: (hi,)),
-            pl.BlockSpec((1, 1, 1, n),
+            pl.BlockSpec((1, 1, tb, p), lambda bi, hi, ti: (bi, hi, ti, 0)),
+            pl.BlockSpec((1, 1, tb, _DT_LANES),
+                         lambda bi, hi, ti: (bi, hi, ti, 0)),
+            _SMEM,
+            pl.BlockSpec((1, 1, tb, n),
                          lambda bi, hi, ti, rep=rep: (bi, hi // rep, ti, 0)),
-            pl.BlockSpec((1, 1, 1, n),
+            pl.BlockSpec((1, 1, tb, n),
                          lambda bi, hi, ti, rep=rep: (bi, hi // rep, ti, 0)),
-            pl.BlockSpec((1,), lambda bi, hi, ti: (hi,)),
+            _SMEM,
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, p), lambda bi, hi, ti: (bi, hi, ti, 0)),
+            pl.BlockSpec((1, 1, tb, p), lambda bi, hi, ti: (bi, hi, ti, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ti: (bi, hi, 0, 0)),
         ],
         out_shape=[
@@ -166,10 +218,11 @@ def ssd_pallas(x, dt, A, B, C, D=None, *, chunk=64, initial_state=None,
     if D is None:
         D = jnp.zeros((h,), jnp.float32)
 
-    # layout: chunk-major per (batch, head)
+    # layout: chunk-major per (batch, head); dt one lane-dense row/chunk
     xk = x.transpose(0, 2, 1, 3).reshape(b, h, nc, chunk, p)
-    dtk = dt.transpose(0, 2, 1).reshape(b, h, nc, chunk)
-    Bk = B.transpose(0, 2, 1, 3).reshape(b, g, nc, chunk, n)
+    dtk = dt.transpose(0, 2, 1).reshape(b, h, nc, 1, chunk)
+    Bk = B.transpose(0, 2, 3, 1).reshape(b, g, n, nc, chunk) \
+          .transpose(0, 1, 3, 2, 4)              # (b, g, nc, n, chunk)
     Ck = C.transpose(0, 2, 1, 3).reshape(b, g, nc, chunk, n)
 
     grid = (b, h, nc)
@@ -180,28 +233,28 @@ def ssd_pallas(x, dt, A, B, C, D=None, *, chunk=64, initial_state=None,
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, p),
                          lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
-            pl.BlockSpec((1, 1, 1, chunk),
-                         lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1,), lambda bi, hi, ci, rep=rep: (hi,)),
-            pl.BlockSpec((1, 1, 1, chunk, n),
+            pl.BlockSpec((1, 1, 1, 1, chunk),
+                         lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
+            _SMEM,
+            pl.BlockSpec((1, 1, 1, n, chunk),
                          lambda bi, hi, ci, rep=rep: (bi, hi // rep, ci, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, n),
                          lambda bi, hi, ci, rep=rep: (bi, hi // rep, ci, 0, 0)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            _SMEM,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, chunk, p),
                          lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
-            pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, n, p), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, nc, chunk, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, n, p), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
     )(xk, dtk, jnp.asarray(A, jnp.float32), Bk, Ck,
       jnp.asarray(D, jnp.float32))
 
     y = y.reshape(b, h, l, p).transpose(0, 2, 1, 3)
-    return y, state
+    return y, state.transpose(0, 1, 3, 2)

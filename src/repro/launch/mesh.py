@@ -6,13 +6,22 @@ touch jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the partitioner propagates
+    shardings through ops, as the param/cache/activation rules assume.
+    (``jax.make_mesh`` defaults to Explicit axes, which reject ops whose
+    operand shardings disagree instead of resharding.)"""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi_pod adds a leading 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def batch_axes(mesh) -> tuple:
@@ -38,7 +47,7 @@ def make_serving_mesh(spec: str = "auto"):
             raise ValueError(f"mesh spec {spec!r}: want 'dp,mp', "
                              f"e.g. '2,4', or 'auto'")
         shape = tuple(parts)
-    return jax.make_mesh(shape, ("data", "model"))
+    return _mesh(shape, ("data", "model"))
 
 
 # TPU v5e hardware constants (per chip) used by the roofline analysis.

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build
 from repro.serving.engine import Engine
 from repro.serving.faults import Faults
@@ -33,6 +34,35 @@ def _engine_kwargs(args):
                 paged=args.paged, page_size=args.page_size,
                 num_pages=args.num_pages or None,
                 mesh=args.mesh or None)
+
+
+def synthetic_prompts(cfg, n, prompt_len, seed):
+    """The seeded request mix ``main`` serves: ``n`` prompts of
+    ``prompt_len // 2 .. prompt_len`` random tokens, each with random
+    frontend embeddings when the arch has a frontend. Returns a list of
+    ``(prompt, embeddings or None)``."""
+    rng = np.random.default_rng(seed)
+    fe = cfg.frontend
+    out = []
+    for _ in range(n):
+        L = int(rng.integers(max(2, prompt_len // 2), prompt_len + 1))
+        emb = None
+        if fe is not None:
+            emb = rng.normal(0, 1, (fe.n_tokens, fe.d_embed)).astype(
+                np.float32)
+        out.append((rng.integers(0, cfg.vocab, L), emb))
+    return out
+
+
+def bytes_by_device(tree):
+    """Bytes of a pytree's arrays held on each device, keyed by device
+    id — how params or a KV cache are spread over a mesh."""
+    out = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            d = shard.device.id
+            out[d] = out.get(d, 0) + shard.data.nbytes
+    return dict(sorted(out.items()))
 
 
 def _parse_drains(spec):
@@ -65,13 +95,10 @@ def _serve_fleet(args, cfg, model, params):
                hedge=args.hedge, trace=bool(args.trace_out),
                faults=(Faults.parse(args.faults, seed=args.faults_seed)
                        if args.faults else None))
-    rng = np.random.default_rng(args.seed)
     t0 = time.perf_counter()
-    for uid in range(args.requests):
-        L = int(rng.integers(max(2, args.prompt_len // 2),
-                             args.prompt_len + 1))
-        fl.submit(Request(uid=uid,
-                          prompt=rng.integers(0, cfg.vocab, L),
+    for uid, (prompt, _) in enumerate(synthetic_prompts(
+            cfg, args.requests, args.prompt_len, args.seed)):
+        fl.submit(Request(uid=uid, prompt=prompt,
                           max_new_tokens=args.max_new,
                           deadline_s=args.deadline or None))
     logger = None
@@ -269,6 +296,7 @@ def main(argv=None):
                          "replica at that wall time, rejoin it once "
                          "drained")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch, variant=args.variant)
     if args.quant:
@@ -292,18 +320,10 @@ def main(argv=None):
                     faults=(Faults.parse(args.faults, seed=args.faults_seed)
                             if args.faults else None))
 
-    rng = np.random.default_rng(args.seed)
-    fe = cfg.frontend
     t0 = time.perf_counter()
-    for uid in range(args.requests):
-        L = int(rng.integers(max(2, args.prompt_len // 2),
-                             args.prompt_len + 1))
-        emb = None
-        if fe is not None:
-            emb = rng.normal(0, 1, (fe.n_tokens, fe.d_embed)).astype(
-                np.float32)
-        engine.submit(Request(uid=uid,
-                              prompt=rng.integers(0, cfg.vocab, L),
+    for uid, (prompt, emb) in enumerate(synthetic_prompts(
+            cfg, args.requests, args.prompt_len, args.seed)):
+        engine.submit(Request(uid=uid, prompt=prompt,
                               max_new_tokens=args.max_new,
                               embeddings=emb,
                               deadline_s=args.deadline or None))
@@ -342,6 +362,8 @@ def main(argv=None):
     responses = engine.run()          # finalize (stops device profiler)
     wall = time.perf_counter() - t0
     stats = engine.latency_stats()
+    stats["param_bytes_by_device"] = bytes_by_device(engine.params)
+    stats["cache_bytes_by_device"] = bytes_by_device(engine.cache)
     if logger is not None:
         logger.log("final", wall_s=wall, **{
             k: v for k, v in stats.items()
@@ -359,6 +381,11 @@ def main(argv=None):
         print(f"mesh: data={shape.get('data', 1)} "
               f"model={shape.get('model', 1)} "
               f"({engine.mesh.devices.size} devices)")
+    mib = lambda by_dev: {d: round(b / 2**20, 1)  # noqa: E731
+                          for d, b in by_dev.items()}
+    print(f"placement MiB by device: "
+          f"params={mib(stats['param_bytes_by_device'])} "
+          f"kv cache={mib(stats['cache_bytes_by_device'])}")
     print(f"finished={stats['n_finished']} "
           f"tokens={stats['tokens_generated']} wall={wall:.2f}s "
           f"({stats['tokens_generated']/wall:,.1f} tok/s)")

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs import ARCHS, get_arch
 from repro.data.pipeline import MarkovLM, batches_for
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build
 from repro.training.checkpoints import save_train_state
 from repro.training.optimizer import AdamW, cosine_schedule
@@ -40,6 +41,7 @@ def main(argv=None):
     ap.add_argument("--metrics", default="",
                     help="JSONL metrics path (machine-readable run log)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch, variant=args.variant)
     model = build(cfg)

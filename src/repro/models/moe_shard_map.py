@@ -27,22 +27,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models.layers import mlp
 
-try:  # jax>=0.6 moved shard_map to the top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-
-def _shard_map_unchecked(*args, **kw):
-    """shard_map without replication checking, across the jax rename
-    (check_rep -> check_vma in jax 0.6)."""
-    import inspect
-    params = inspect.signature(shard_map).parameters
-    flag = "check_vma" if "check_vma" in params else "check_rep"
-    kw[flag] = False
-    return shard_map(*args, **kw)
-
-
 def _local_expert_pass(router_w, wi, wg, wo, x, *, cfg: ModelConfig,
                        axis: str, n_shards: int, data_axes=("data",)):
     """Per-rank body. x: (B_loc, L, d) — same tokens on every model rank.
@@ -122,11 +106,12 @@ def moe_block_shard_map(p, x, cfg: ModelConfig, mesh, *,
 
     body = functools.partial(_local_expert_pass, cfg=cfg, axis=axis,
                              n_shards=n_shards, data_axes=b)
-    fn = _shard_map_unchecked(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(axis, None, None), P(axis, None, None),
                   P(axis, None, None), P(batch, None, None)),
         out_specs=(P(batch, None, None), P()),
+        check_vma=False,
     )
     y, aux = fn(p["router"]["w"].astype(jnp.float32), p["wi"], p["wg"],
                 p["wo"], x)

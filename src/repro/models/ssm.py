@@ -50,12 +50,17 @@ def make_ssm_cache(batch, cfg: ModelConfig, dtype):
 
 
 def _causal_conv(x, w, b):
-    """Depthwise causal conv. x: (B, L, Cc), w: (Cc, K)."""
+    """Depthwise causal conv. x: (B, L, Cc), w: (Cc, K). Computed in
+    float32, like the extend and decode steps' convs, so prefill agrees
+    with the serving path that continues from it; in bf16 the two drift
+    apart with depth."""
+    f32 = jnp.float32
     K = w.shape[1]
     L = x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    xp = jnp.pad(x.astype(f32), ((0, 0), (K - 1, 0), (0, 0)))
+    w = w.astype(f32)
     out = sum(xp[:, i:i + L] * w[None, None, :, i] for i in range(K))
-    return out + b
+    return out + b.astype(f32)
 
 
 def _split_proj(zxbcdt, cfg: ModelConfig):

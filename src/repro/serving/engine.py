@@ -2413,30 +2413,24 @@ class Engine:
         """Drive the optional ``jax.profiler`` device-trace window
         (``trace_dir=``): start after the first step (so the first
         compile doesn't dominate the capture), stop after
-        ``profile_steps`` steps. Failures (profiler unavailable,
-        directory not writable) disable the capture, never the run."""
+        ``profile_steps`` steps. A trace was asked for, so a profiler
+        that fails to start or stop fails the run."""
         if not self._trace_dir or self._prof_done:
             return
         if not self._prof_on:
             if self._steps >= 1:
-                try:
-                    jax.profiler.start_trace(self._trace_dir)
-                    self._prof_on = True
-                    self._prof_base = self._steps
-                except Exception:
-                    self._prof_done = True
+                jax.profiler.start_trace(self._trace_dir)
+                self._prof_on = True
+                self._prof_base = self._steps
         elif self._steps - self._prof_base >= self._profile_steps:
             self._stop_profile()
 
     def _stop_profile(self) -> None:
         if self._prof_on:
-            try:
-                jax.block_until_ready(self.tokens)
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
             self._prof_on = False
             self._prof_done = True
+            jax.block_until_ready(self.tokens)
+            jax.profiler.stop_trace()
 
     # ------------------------------------------------------------ #
     @staticmethod

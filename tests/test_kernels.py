@@ -179,6 +179,34 @@ def test_decode_attention_matches_model_path():
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("window", [0, 16])
+def test_paged_decode_attention_kernel(window):
+    """Paged kernel: K/V pages fetched through the block table (shuffled
+    page order, one partly filled sequence) == the gathered oracle."""
+    from repro.kernels.decode_attention.kernel import (
+        paged_decode_attention_pallas)
+    from repro.kernels.decode_attention.ref import (
+        paged_decode_attention_reference)
+    B, T, Hq, Hkv, hd, ps, NB = 2, 3, 8, 2, 32, 16, 4
+    S = NB * ps
+    q = _arr((B, T, Hq, hd))
+    k_pool = _arr((B * NB + 1, ps, Hkv, hd))
+    v_pool = _arr((B * NB + 1, ps, Hkv, hd))
+    bt = jnp.asarray(rng.permutation(B * NB).reshape(B, NB), jnp.int32)
+    pos = np.full((B, S), -1, np.int32)
+    pos[0, :40] = np.arange(40)
+    pos[1] = np.arange(S)
+    q_pos = jnp.asarray([[37, 38, 39], [S - 3, S - 2, S - 1]], jnp.int32)
+    out = paged_decode_attention_pallas(q, k_pool, v_pool, bt,
+                                        jnp.asarray(pos), q_pos,
+                                        window=window, interpret=True)
+    ref = paged_decode_attention_reference(q, k_pool, v_pool, bt,
+                                           jnp.asarray(pos), q_pos,
+                                           window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
+
+
 # ------------------------------------------------------------------ #
 # SSD scan
 # ------------------------------------------------------------------ #
@@ -201,6 +229,30 @@ def test_ssd_scan(b, l, h, p, g, n, chunk):
                                rtol=1e-3, atol=1e-3)
     np.testing.assert_allclose(np.asarray(s), np.asarray(sr),
                                rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("b,T,h,p,g,n", [
+    (2, 5, 4, 16, 2, 32), (1, 256, 4, 32, 1, 64), (1, 384, 2, 64, 1, 128),
+])
+def test_ssd_extend_kernel(b, T, h, p, g, n):
+    """Sequential extend from a carried state: one token block per grid
+    step (T = 384 spans three 128-token blocks), state carried across
+    blocks in VMEM scratch."""
+    from repro.kernels.ssd_scan.kernel import ssd_extend_pallas
+    from repro.kernels.ssd_scan.ref import ssd_extend_reference
+    state = _arr((b, h, p, n))
+    x = _arr((b, T, h, p))
+    dt = jnp.asarray(rng.uniform(1e-3, 0.1, (b, T, h)), jnp.float32)
+    A = jnp.asarray(-rng.uniform(0.5, 2.0, (h,)), jnp.float32)
+    Bm = _arr((b, T, g, n))
+    Cm = _arr((b, T, g, n))
+    D = _arr((h,))
+    y, s = ssd_extend_pallas(state, x, dt, A, Bm, Cm, D, interpret=True)
+    yr, sr = ssd_extend_reference(state, x, dt, A, Bm, Cm, D)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(sr),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_ssd_decode_step_matches_scan():

@@ -105,3 +105,41 @@ def test_ssd_chunk_padding_path():
     y2, _ = ssm_block(p, u[:, :17], cfg)
     np.testing.assert_allclose(np.asarray(y[:, :17]), np.asarray(y2),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_compile_cache_set_by_env_is_left_alone(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself and the
+    helper configures no directory of its own."""
+    from repro.launch import compile_cache as cc
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.setenv(cc.ENV, str(tmp_path))
+    assert cc.enable_compile_cache() == str(tmp_path)
+    assert calls == []
+
+
+def test_compile_cache_default_is_fixed_in_checkout(monkeypatch):
+    """Unset: one fixed path inside the checkout (the cache key includes
+    the directory), the same on every call, and ignored by git."""
+    from pathlib import Path
+    from repro.launch import compile_cache as cc
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.delenv(cc.ENV, raising=False)
+    repo = Path(__file__).resolve().parents[1]
+    first, second = cc.enable_compile_cache(), cc.enable_compile_cache()
+    assert first == second == str(repo / ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", first)] * 2
+    assert "/.jax_cache/" in (repo / ".gitignore").read_text().split()
+
+
+def test_chip_smoke_fails_without_tpu(monkeypatch, tmp_path, capsys):
+    """On the CPU backend the chip check exits non-zero and prints no
+    result line — it never falls back to the CPU."""
+    import chip_smoke
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert jax.default_backend() == "cpu"
+    assert chip_smoke.main([]) != 0
+    out, err = capsys.readouterr()
+    assert '"ok": true' not in out
+    assert "no TPU" in err

@@ -71,7 +71,8 @@ from repro.configs import SHAPES
 from repro.launch.steps import build_step, activation_rules_for
 from repro.distribution.sharding import activation_sharding
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 for arch, shape in [("llama3.2-1b", "decode_32k"),
                     ("qwen2-moe-a2.7b", "train_4k"),
                     ("mamba2-780m", "prefill_32k")]:

@@ -216,3 +216,19 @@ def test_steady_state_recompile_warns():
     c = eng.metrics.snapshot()["counters"]
     assert c["steady_compiles"] >= 1
     assert c["compiles_total"] > c["steady_compiles"]   # warmup counted too
+
+
+@pytest.mark.parametrize("site", ["start_trace", "stop_trace"])
+def test_trace_dir_profiler_failure_fails_run(monkeypatch, tmp_path, site):
+    """A device trace was asked for: a profiler that cannot start or stop
+    fails the run instead of leaving it to exit cleanly with no trace."""
+    def broken(*a, **k):
+        raise RuntimeError(f"profiler {site} failed")
+    started = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: started.append(d))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    monkeypatch.setattr(jax.profiler, site, broken)
+    eng = _engine(trace_dir=str(tmp_path), profile_steps=1)
+    with pytest.raises(RuntimeError, match=f"profiler {site} failed"):
+        _stream(eng)
