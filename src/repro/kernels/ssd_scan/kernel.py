@@ -199,6 +199,7 @@ def ssd_extend_pallas(state, x, dt, A, B, C, D=None, *, interpret=False):
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_extend",
     )(state.astype(jnp.float32), xk, dtk, jnp.asarray(A, jnp.float32),
       Bk, Ck, jnp.asarray(D, jnp.float32))
 
@@ -253,6 +254,7 @@ def ssd_pallas(x, dt, A, B, C, D=None, *, chunk=64, initial_state=None,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(xk, dtk, jnp.asarray(A, jnp.float32), Bk, Ck,
       jnp.asarray(D, jnp.float32))
 

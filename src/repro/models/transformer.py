@@ -189,33 +189,34 @@ def apply_block(bp, x, cfg: ModelConfig, *, mode: str, cache=None,
     for i, (mixer, ffn) in enumerate(spec):
         sp = bp[f"sub{i}"]
         h = L.rms_norm(sp["ln1"], x, cfg.norm_eps)
-        if mixer == "attn":
-            if mode == "train":
-                y, nc = L.attention_block(sp["attn"], h, cfg)
-            elif mode == "prefill":
-                y, nc = L.prefill_into_cache(sp["attn"], h, cfg,
-                                             cache[f"sub{i}"],
-                                             length=length)
-            elif mode == "extend":
-                y, nc = L.extend_into_cache(sp["attn"], h, cfg,
-                                            cache[f"sub{i}"],
-                                            lengths=length)
+        with jax.named_scope("attention" if mixer == "attn" else "ssm"):
+            if mixer == "attn":
+                if mode == "train":
+                    y, nc = L.attention_block(sp["attn"], h, cfg)
+                elif mode == "prefill":
+                    y, nc = L.prefill_into_cache(sp["attn"], h, cfg,
+                                                 cache[f"sub{i}"],
+                                                 length=length)
+                elif mode == "extend":
+                    y, nc = L.extend_into_cache(sp["attn"], h, cfg,
+                                                cache[f"sub{i}"],
+                                                lengths=length)
+                else:
+                    y, nc = L.attention_block(sp["attn"], h, cfg,
+                                              cache=cache[f"sub{i}"])
             else:
-                y, nc = L.attention_block(sp["attn"], h, cfg,
-                                          cache=cache[f"sub{i}"])
-        else:
-            if mode == "train":
-                y, nc = S.ssm_block(sp["ssm"], h, cfg)
-            elif mode == "prefill":
-                y, nc = S.ssm_block(sp["ssm"], h, cfg, return_cache=True,
-                                    length=length)
-            elif mode == "extend":
-                y, nc = S.ssm_block(sp["ssm"], h, cfg,
-                                    cache=cache[f"sub{i}"],
-                                    length=length, mode="extend")
-            else:
-                y, nc = S.ssm_block(sp["ssm"], h, cfg,
-                                    cache=cache[f"sub{i}"])
+                if mode == "train":
+                    y, nc = S.ssm_block(sp["ssm"], h, cfg)
+                elif mode == "prefill":
+                    y, nc = S.ssm_block(sp["ssm"], h, cfg, return_cache=True,
+                                        length=length)
+                elif mode == "extend":
+                    y, nc = S.ssm_block(sp["ssm"], h, cfg,
+                                        cache=cache[f"sub{i}"],
+                                        length=length, mode="extend")
+                else:
+                    y, nc = S.ssm_block(sp["ssm"], h, cfg,
+                                        cache=cache[f"sub{i}"])
         if nc is not None:
             new_cache[f"sub{i}"] = nc
         x = x + y
@@ -223,10 +224,13 @@ def apply_block(bp, x, cfg: ModelConfig, *, mode: str, cache=None,
         if ffn is not None:
             h = L.rms_norm(sp["ln2"], x, cfg.norm_eps)
             if ffn == "mlp":
-                y = L.mlp(sp["mlp"], h)
+                with jax.named_scope("mlp"):
+                    y = L.mlp(sp["mlp"], h)
             else:
-                y, moe_aux = M.moe_block(sp["moe"], h, cfg,
-                                         dense=mode in ("decode", "extend"))
+                with jax.named_scope("moe_ffn"):
+                    y, moe_aux = M.moe_block(
+                        sp["moe"], h, cfg,
+                        dense=mode in ("decode", "extend"))
                 aux = aux + moe_aux
             x = x + y
             x = shard_activation(x, "act_btd")

@@ -70,16 +70,21 @@ Telemetry (``docs/observability.md``): every host-side stat lives in
 one ``serving/telemetry.MetricsRegistry`` (``Engine.metrics``) —
 counters (tokens emitted, steps by kind, admissions, spec
 accept/emit), gauges sampled at each poll (active slots, free pages,
-KV bytes per live token), bounded-reservoir histograms (TTFT, ITL) and
-the per-step wall/kind series ``latency_stats()`` is built on. Request
-lifecycles route through a ``Recorder`` (no-op by default; pass
-``recorder=True`` for a ``serving/tracing.Tracer`` and export a
-Perfetto-loadable Chrome trace with ``Engine.export_trace(path)``).
+KV bytes per live token), bounded-reservoir histograms (queue wait,
+TTFT, ITL) and the per-step wall/kind series ``latency_stats()`` is
+built on. Request lifecycles route through a ``Recorder`` (no-op by
+default; pass ``recorder=True`` for a ``serving/tracing.Tracer`` and
+export a Perfetto-loadable Chrome trace with
+``Engine.export_trace(path)``).
 Every jitted program is watched for XLA compiles: after
 ``reset_stats()``/``mark_steady()`` arms the watchdog, a steady-state
 compile raises ``telemetry.RecompileWarning`` and increments the
 ``steady_compiles`` counter CI fails on. ``trace_dir=`` additionally
-captures a ``jax.profiler`` device trace over a short step window.
+captures a ``jax.profiler`` device trace over a short step window, in
+which ``tick`` marks its phases with ``engine:*`` host spans
+(``tracing.span``) and the programs' ops carry named scopes
+(``decode``, ``prefill``, ``sample``; the model's blocks add
+``attention``, ``ssm``, ``mlp``, ``moe_ffn``).
 
 Resilience (``docs/robustness.md``): requests carry ``deadline_s`` and
 ``priority``; ``Engine.cancel(uid)`` and per-poll deadline enforcement
@@ -114,6 +119,7 @@ from repro.serving import paged_kv, telemetry
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.request import Request, Response
 from repro.serving.sampler import Sampler
+from repro.serving.tracing import span
 
 #: Sentinel "token" the fused steps emit for a slot whose sampler logits
 #: were not finite (NaN/inf): the on-device guard deactivates only that
@@ -131,9 +137,10 @@ def _guarded_sample(sampler, key, logits):
     are per-row functions of per-row logits). Finite rows are
     bit-identical to an unguarded call: the ``where`` masks select the
     original logits elementwise."""
-    bad = ~jnp.all(jnp.isfinite(logits), axis=-1)                # (B,)
-    safe = jnp.where(bad[:, None], 0.0, logits)
-    nxt = jnp.where(bad, jnp.int32(ERR_TOKEN), sampler(key, safe))
+    with jax.named_scope("sample"):
+        bad = ~jnp.all(jnp.isfinite(logits), axis=-1)            # (B,)
+        safe = jnp.where(bad[:, None], 0.0, logits)
+        nxt = jnp.where(bad, jnp.int32(ERR_TOKEN), sampler(key, safe))
     return nxt, bad
 
 
@@ -344,6 +351,7 @@ class Engine:
         self._c_fallback = self.metrics.counter("fallback_admissions")
         self._c_spec_emitted = self.metrics.counter("spec_tokens_emitted")
         self._c_spec_steps = self.metrics.counter("spec_active_steps")
+        self._h_queue = self.metrics.histogram("queue_wait_s")
         self._h_ttft = self.metrics.histogram("ttft_s")
         self._h_itl = self.metrics.histogram("itl_s")
         self._c_preempt = self.metrics.counter("preemptions")
@@ -702,9 +710,10 @@ class Engine:
         if self.paged:
             def step(params, cache, tokens, remaining, active, eos, key,
                      poison):
-                logits, cache = model.extend_into_cache(
-                    params, tokens, cache, active.astype(jnp.int32),
-                    last_only=True)
+                with jax.named_scope("decode"):
+                    logits, cache = model.extend_into_cache(
+                        params, tokens, cache, active.astype(jnp.int32),
+                        last_only=True)
                 key, sk = jax.random.split(key)
                 nxt, bad = _guarded_sample(
                     sampler, sk,
@@ -718,7 +727,8 @@ class Engine:
         else:
             def step(params, cache, tokens, remaining, active, eos, key,
                      poison):
-                logits, cache = model.decode_step(params, tokens, cache)
+                with jax.named_scope("decode"):
+                    logits, cache = model.decode_step(params, tokens, cache)
                 key, sk = jax.random.split(key)
                 nxt, bad = _guarded_sample(                        # (B,)
                     sampler, sk,
@@ -804,12 +814,14 @@ class Engine:
             B = tokens.shape[0]
             bidx = jnp.arange(B)
             is_admit = bidx == a_slot
-            dec_logits, cache = model.extend_into_cache(
-                params, tokens, cache, active.astype(jnp.int32),
-                last_only=True)
-            ch_logits, cache = self._slot_extend(
-                model, params, cache, a_slot, chunk, a_len,
-                paged=is_paged)
+            with jax.named_scope("decode"):
+                dec_logits, cache = model.extend_into_cache(
+                    params, tokens, cache, active.astype(jnp.int32),
+                    last_only=True)
+            with jax.named_scope("prefill"):
+                ch_logits, cache = self._slot_extend(
+                    model, params, cache, a_slot, chunk, a_len,
+                    paged=is_paged)
             logits = jnp.where(is_admit[:, None], ch_logits[0, 0][None],
                                dec_logits[:, 0])
             key, sk = jax.random.split(key)
@@ -857,11 +869,12 @@ class Engine:
             B = tokens.shape[0]
             bidx = jnp.arange(B)
             is_admit = bidx == a_slot
-            logits, cache = self._slot_extend(
-                model, params, cache, a_slot, chunk, a_len,
-                paged=is_paged)
-            _, dcache = self._slot_extend(
-                draft, dparams, dcache, a_slot, chunk, d_len)
+            with jax.named_scope("prefill"):
+                logits, cache = self._slot_extend(
+                    model, params, cache, a_slot, chunk, a_len,
+                    paged=is_paged)
+                _, dcache = self._slot_extend(
+                    draft, dparams, dcache, a_slot, chunk, d_len)
             key, sk = jax.random.split(key)
             nxt, bad = _guarded_sample(                          # (1,)
                 sampler, sk,
@@ -1036,9 +1049,10 @@ class Engine:
             H = hist.shape[1]
             bidx = jnp.arange(B)
             is_admit = bidx == a_slot
-            logits, cache = self._slot_extend(
-                model, params, cache, a_slot, chunk, a_len,
-                paged=is_paged)
+            with jax.named_scope("prefill"):
+                logits, cache = self._slot_extend(
+                    model, params, cache, a_slot, chunk, a_len,
+                    paged=is_paged)
             key, sk = jax.random.split(key)
             nxt, bad = _guarded_sample(                          # (1,)
                 sampler, sk,
@@ -1639,7 +1653,9 @@ class Engine:
         generated, replayed chunk by chunk — teacher-forcing the model
         through its own earlier output, so the token sampled on arming
         (and every one after) matches the unpreempted run."""
-        req.started_s = req.started_s or time.perf_counter()
+        if not req.started_s:                 # first admission, not a resume
+            req.started_s = time.perf_counter()
+            self._h_queue.observe(req.started_s - req.submitted_s)
         done = self.responses[req.uid].tokens
         eff = np.asarray(req.prompt, np.int32)
         if done:
@@ -1903,19 +1919,22 @@ class Engine:
             # the burst boundary
             b = self._free_slot()
             if b is not None and self._admit_fits(self.queue[0]):
-                self._start_chunked(self.queue.popleft(), b)
+                with span("admit"):
+                    self._start_chunked(self.queue.popleft(), b)
         adm = self._admit
         if self.spec_gamma:
             if adm is not None:
-                self._step_admit_chunk(adm)
-                if self.active_slots:
+                with span("admit_chunk"):
+                    self._step_admit_chunk(adm)
+            if adm is None or self.active_slots:
+                with span("spec"):
                     self._step_spec()
-            else:
-                self._step_spec()
         elif adm is not None:
-            self._step_mixed(adm)
+            with span("mixed"):
+                self._step_mixed(adm)
         else:
-            self._step_plain()
+            with span("plain"):
+                self._step_plain()
         if poisoned:
             self._clear_poison()
         made = self._steps - n0
@@ -2296,13 +2315,15 @@ class Engine:
         k = self.sync_every if steps is None else max(1, steps)
         if self._deadline_armed:
             self._enforce_deadlines(include_active=False)
-        self._fill_free_slots()
+        with span("admit"):
+            self._fill_free_slots()
         if not (self.active_slots or self._admit is not None):
-            self._poll()
+            with span("poll"):
+                self._poll()
             if self._deadline_armed:
                 self._enforce_deadlines()
             return 0
-        t0 = t_begin = time.perf_counter()
+        t0 = time.perf_counter()
         # steps run outside tick (raw .step() calls) have no wall stamp;
         # backfill so gap indexing stays aligned with the step counter
         while len(self._step_wall) + self._step_wall_base < self._steps:
@@ -2325,7 +2346,8 @@ class Engine:
                 self._step_wall.extend([now] * made)
                 t0 = now
                 n0 = len(self.step_times)
-        jax.block_until_ready(self.tokens)
+        with span("sync"):
+            jax.block_until_ready(self.tokens)
         t1 = time.perf_counter()
         m = len(self.step_times) - n0
         if m > 0:
@@ -2335,19 +2357,9 @@ class Engine:
                 self.step_times[i] = dt
             for i in range(m):
                 self._step_wall.append(t0 + dt * (i + 1))
-        if self.recorder.enabled and self._steps > ran0:
-            # finalised per-step spans for the trace's steps lane: each
-            # step ends at its wall stamp and starts at its
-            # predecessor's (the burst entry for the first)
-            spans = []
-            for g in range(ran0, self._steps):
-                w = g - self._step_wall_base
-                start = self._step_wall[w - 1] if w > 0 else t_begin
-                spans.append((start, self._step_wall[w],
-                              self.step_kinds[g - self._kinds_base]))
-            self.recorder.on_steps(spans)
         self._stamp_first_tokens(t1)
-        self._poll()
+        with span("poll"):
+            self._poll()
         if self._deadline_armed:
             self._enforce_deadlines()
         self._maybe_profile()
@@ -2442,10 +2454,10 @@ class Engine:
         telemetry.pct_stats(stats, prefix, samples, pcts)
 
     def latency_stats(self) -> Dict[str, float]:
-        """Latency summary. The ``decode_ms_*`` / ``ttft_ms_*`` /
-        ``itl_ms_*`` keys are present only when the corresponding stream
-        has at least one sample — a fresh (or reset) engine reports the
-        counters alone."""
+        """Latency summary. The ``decode_ms_*`` / ``queue_wait_ms_*`` /
+        ``ttft_ms_*`` / ``itl_ms_*`` keys are present only when the
+        corresponding stream has at least one sample — a fresh (or
+        reset) engine reports the counters alone."""
         drop = 1 if self._drop_compile_step else 0
         finished = [r for r in self.responses.values() if r.finished]
         stats: Dict[str, float] = {
@@ -2463,6 +2475,8 @@ class Engine:
         }
         telemetry.pct_stats(stats, "decode_ms", self.step_times[drop:],
                             (50, 99))
+        telemetry.pct_stats(stats, "queue_wait_ms", self._h_queue.values,
+                            (50, 95, 99))
         telemetry.pct_stats(stats, "ttft_ms", self._h_ttft.values,
                             (50, 95, 99))
         telemetry.pct_stats(stats, "itl_ms", self._h_itl.values,

@@ -51,7 +51,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from repro.serving import faults as faults_mod
-from repro.serving import telemetry
+from repro.serving import telemetry, tracing
 from repro.serving.engine import Engine
 from repro.serving.request import Request, Response
 
@@ -144,7 +144,7 @@ class Fleet:
 
         if replicas < 1:
             raise ValueError(f"fleet needs >= 1 replica, got {replicas}")
-        self._t0 = time.perf_counter()
+        self._anchor = tracing.clock_anchor()     # fleet lane's clock
         self.model, self.params = model, params
         self.engine_kwargs = dict(engine_kwargs or {})
         self.engine_kwargs.pop("recorder", None)
@@ -776,23 +776,21 @@ class Fleet:
         ``100 + rid``) plus a fleet lane (pid 99) of orchestration
         instants (health transitions, failovers, hedges, drains).
         Requires ``Fleet(..., trace=True)``."""
-        from repro.serving.tracing import merge_chrome_traces
         parts = []
         for r in self.replicas:
             exp = getattr(r.engine, "recorder", None)
             exp = getattr(exp, "export_chrome_trace", None)
             if r.engine is None or exp is None:
                 continue
-            off = (r.engine.recorder.t0 - self._t0) * 1e6
-            parts.append((f"replica {r.rid}", 100 + r.rid, exp(), off))
+            parts.append((f"replica {r.rid}", 100 + r.rid, exp()))
         if not parts:
             raise RuntimeError("export_trace needs Fleet(..., "
                                "trace=True)")
         fleet_events = [
             {"name": ev["name"], "ph": "i",
-             "ts": round((ev["ts"] - self._t0) * 1e6, 1),
+             "ts": tracing.profiler_us(ev["ts"], self._anchor),
              "pid": 99, "tid": 0, "s": "t", "args": ev["args"]}
             for ev in self._events]
-        return merge_chrome_traces(parts, extra=fleet_events,
-                                   extra_label="fleet", extra_pid=99,
-                                   path=path)
+        return tracing.merge_chrome_traces(
+            parts, extra=fleet_events, extra_label="fleet", extra_pid=99,
+            path=path)

@@ -294,9 +294,6 @@ class Recorder:
     def on_fault(self, site: str, step: int, ts: float) -> None:
         """A scheduled fault fired at ``site`` (serving/faults.py)."""
 
-    def on_steps(self, spans: List[Tuple[float, float, str]]) -> None:
-        """Finalised step timings for one burst: (start, end, kind)."""
-
     def on_poll(self, ts: float, active: int,
                 stats: Dict[str, float]) -> None:
         """Periodic host sync: live occupancy / pool sample."""

@@ -1,58 +1,93 @@
-"""Request-lifecycle tracing: Chrome trace-event export for serving runs.
+"""Request-lifecycle tracing: Chrome trace-event export for serving runs,
+and the engine's host spans in the profiler's own trace.
 
 :class:`Tracer` is the recording :class:`~repro.serving.telemetry.Recorder`
 implementation: the engine feeds it span events for every request
 (enqueued → admission chunks → first token → per-poll emissions →
-finished/evicted), finalised per-step timings, poll-time pool samples
-and compile events, all host-side with monotonic
-(``time.perf_counter``) timestamps. ``export_chrome_trace`` renders the
-collected run as Chrome trace-event JSON — open it at ``ui.perfetto.dev``
-(or ``chrome://tracing``) and the run reads as:
+finished/evicted), poll-time pool samples and compile events, all
+host-side. ``export_chrome_trace`` renders the collected run as Chrome
+trace-event JSON — open it at ``ui.perfetto.dev`` (or
+``chrome://tracing``) and the run reads as:
 
 * one lane per batch **slot** (``slot 0..B-1``): a complete span per
   request occupying it, with instants for admission chunks, the first
   token, and each poll's token emissions;
 * a **queue** lane: per-request wait between ``submit`` and admission;
-* a **steps** lane: one slice per fused engine step, named by kind
-  (``plain`` / ``mixed`` / ``admit`` / ``spec``);
 * a **compiles** lane: every XLA compile with its elapsed wall
   (steady-state ones flagged — the recompile watchdog's signal);
 * counter tracks for **active slots** and **page-pool occupancy**
   (live/free pages), sampled at every poll.
 
-Timestamps are microseconds relative to tracer construction (the
-engine's, when built with ``recorder=True``). The tracer is pure host
-bookkeeping: it never touches device state, so a traced run's greedy
-outputs and compiled-program counts are bit-identical to an untraced
-one (asserted in ``tests/test_telemetry.py``).
+What each engine step did on the host and the device is in the
+``jax.profiler`` trace instead: :func:`span` opens the engine's
+``engine:<phase>`` host spans there (always on; without a profiler
+session a span is a constructor and an atomic check), beside the
+device ops of the same run.
+
+One clock: the profiler stamps with the system clock (``time.time_ns``,
+nanoseconds since the epoch; an ``.xplane.pb`` stores its times as
+offsets from the session's ``profile_start_time``). The tracer reads
+``time.perf_counter`` for its events and converts them on export with
+an anchor pair read at construction (:func:`clock_anchor`), so its
+``ts`` are microseconds on the profiler's clock: every replica's export
+and a profile of the same run share one time base, and
+:func:`profile_trace` renders a profile on it for
+:func:`merge_chrome_traces`. The tracer is pure host bookkeeping: it
+never touches device state, so a traced run's greedy outputs and
+compiled-program counts are bit-identical to an untraced one (asserted
+in ``tests/test_telemetry.py``).
 """
 from __future__ import annotations
 
+import glob
 import json
+import os
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
+
 from repro.serving.telemetry import Recorder
 
-__all__ = ["Tracer", "validate_chrome_trace", "complete_spans",
+__all__ = ["Tracer", "span", "clock_anchor", "profiler_us",
+           "profile_trace", "validate_chrome_trace", "complete_spans",
            "merge_chrome_traces"]
+
+SPAN_PREFIX = "engine:"
 
 # fixed thread-lane ids (slot lanes are 1..max_batch)
 QUEUE_TID = 0
-STEP_TID = 900
 COMPILE_TID = 901
 FAULT_TID = 902
 _PID = 1
+
+
+def span(phase: str) -> jax.profiler.TraceAnnotation:
+    """The host span ``engine:<phase>`` in the ``jax.profiler`` trace,
+    as a context manager."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + phase)
+
+
+def clock_anchor() -> Tuple[float, int]:
+    """``(time.perf_counter(), profiler clock in ns)``, read back to
+    back."""
+    return time.perf_counter(), time.time_ns()
+
+
+def profiler_us(t: float, anchor: Tuple[float, int]) -> float:
+    """A ``time.perf_counter`` stamp as microseconds on the profiler's
+    clock, through ``anchor`` (:func:`clock_anchor`)."""
+    pc, ns = anchor
+    return round((ns + (t - pc) * 1e9) * 1e-3, 1)
 
 
 class Tracer(Recorder):
     enabled = True
 
     def __init__(self):
-        self.t0 = time.perf_counter()
+        self.anchor = clock_anchor()
         # uid -> lifecycle record (insertion order = submit order)
         self.requests: Dict[int, Dict[str, Any]] = {}
-        self.steps: List[Tuple[float, float, str]] = []
         self.polls: List[Tuple[float, int, Dict[str, float]]] = []
         self.compiles: List[Tuple[float, str, float, bool]] = []
         self.faults: List[Tuple[float, str, int]] = []
@@ -106,9 +141,6 @@ class Tracer(Recorder):
     def on_fault(self, site: str, step: int, ts: float) -> None:
         self.faults.append((ts, site, step))
 
-    def on_steps(self, spans: List[Tuple[float, float, str]]) -> None:
-        self.steps.extend(spans)
-
     def on_poll(self, ts: float, active: int,
                 stats: Dict[str, float]) -> None:
         self.polls.append((ts, active, dict(stats)))
@@ -119,7 +151,7 @@ class Tracer(Recorder):
 
     # -- export -------------------------------------------------------- #
     def _us(self, t: float) -> float:
-        return round((t - self.t0) * 1e6, 1)
+        return profiler_us(t, self.anchor)
 
     def export_chrome_trace(self, path: Optional[str] = None
                             ) -> Dict[str, Any]:
@@ -143,7 +175,6 @@ class Tracer(Recorder):
                        if r["slot"] is not None})
         for b in slots:
             meta(1 + b, f"slot {b}")
-        meta(STEP_TID, "steps")
         meta(COMPILE_TID, "compiles")
         if self.faults:
             meta(FAULT_TID, "faults")
@@ -200,17 +231,13 @@ class Tracer(Recorder):
                            "ts": self._us(r["finished"]), "pid": _PID,
                            "tid": tid, "s": "t", "args": {"uid": uid}})
 
-        for (start, end, kind) in self.steps:
-            ev.append({"name": kind, "ph": "X", "ts": self._us(start),
-                       "dur": max(0.0, round((end - start) * 1e6, 1)),
-                       "pid": _PID, "tid": STEP_TID})
         for (t, site, step) in self.faults:
             ev.append({"name": f"fault {site}", "ph": "i",
                        "ts": self._us(t), "pid": _PID, "tid": FAULT_TID,
                        "s": "t", "args": {"site": site, "step": step}})
         for (t, name, elapsed, steady) in self.compiles:
             ev.append({"name": f"compile {name}", "ph": "X",
-                       "ts": self._us(max(t, self.t0)),
+                       "ts": self._us(max(t, self.anchor[0])),
                        "dur": round(elapsed * 1e6, 1),
                        "pid": _PID, "tid": COMPILE_TID,
                        "args": {"steady": bool(steady)}})
@@ -232,35 +259,65 @@ class Tracer(Recorder):
 
 
 # --------------------------------------------------------------------- #
-# multi-process merge (fleet serving)
+# a profile on the same clock, and the multi-process merge
 # --------------------------------------------------------------------- #
+def profile_trace(trace_dir: str) -> Dict[str, Any]:
+    """The newest ``jax.profiler`` session under ``trace_dir`` (an
+    engine's ``trace_dir=`` / ``serve.py --trace-dir``) as a Chrome
+    trace-event object on the profiler's clock, the time base of a
+    :class:`Tracer` export: one lane per line of each plane (host
+    threads with their ``engine:*`` spans, each device's XLA ops and
+    modules), thread names ``<plane> <line>``."""
+    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    prof = jax.profiler.ProfileData.from_file(
+        max(paths, key=os.path.getmtime))
+    origin = None
+    for plane in prof.planes:
+        for key, value in plane.stats:
+            if key == "profile_start_time":
+                origin = int(value)
+    if origin is None:
+        raise ValueError(f"{trace_dir}: the profile has no "
+                         f"profile_start_time")
+    ev: List[Dict[str, Any]] = []
+    tid = 0
+    for plane in prof.planes:
+        for line in plane.lines:
+            tid += 1
+            ev.append({"name": "thread_name", "ph": "M", "ts": 0,
+                       "pid": _PID, "tid": tid,
+                       "args": {"name": f"{plane.name} {line.name}"}})
+            ev.extend({"name": e.name, "ph": "X",
+                       "ts": round((origin + e.start_ns) * 1e-3, 3),
+                       "dur": round(e.duration_ns * 1e-3, 3),
+                       "pid": _PID, "tid": tid} for e in line.events)
+    return {"traceEvents": ev, "displayTimeUnit": "ms"}
+
+
 def merge_chrome_traces(parts, extra=None, extra_label: str = "fleet",
                         extra_pid: int = 99,
                         path: Optional[str] = None) -> Dict[str, Any]:
-    """Merge per-replica Chrome traces into one multi-process trace.
+    """Merge Chrome traces on the profiler's clock (replica tracers,
+    :func:`profile_trace`) into one multi-process trace.
 
-    ``parts`` is a list of ``(label, pid, trace, offset_us)`` tuples:
-    every event in ``trace`` is rewritten onto process ``pid`` (named
-    ``label``) and shifted by ``offset_us`` — each replica tracer's
-    timestamps are relative to its own construction, so the caller
-    (``serving/fleet.py``) passes the tracer-epoch offset that aligns
-    them on one fleet clock. ``extra`` is an optional list of
-    ready-made events for an orchestration lane on ``extra_pid``
-    (health transitions, failovers, hedges). Rejoined replicas carry a
-    fresh tracer; the merge simply reflects whatever each current
-    tracer recorded."""
+    ``parts`` is a list of ``(label, pid, trace)`` tuples: every event in
+    ``trace`` is rewritten onto process ``pid`` (named ``label``); its
+    ``ts`` stays as it is, since every part shares the clock. ``extra``
+    is an optional list of ready-made events for an orchestration lane
+    on ``extra_pid`` (health transitions, failovers, hedges). Rejoined
+    replicas carry a fresh tracer; the merge simply reflects whatever
+    each current tracer recorded."""
     ev: List[Dict[str, Any]] = []
-    for label, pid, trace, offset_us in parts:
+    for label, pid, trace in parts:
         ev.append({"name": "process_name", "ph": "M", "ts": 0,
                    "pid": pid, "tid": 0, "args": {"name": label}})
         for e in trace.get("traceEvents", ()):
             if e.get("ph") == "M" and e.get("name") == "process_name":
                 continue
-            e2 = dict(e)
-            e2["pid"] = pid
-            if e.get("ph") != "M":
-                e2["ts"] = round(e.get("ts", 0) + offset_us, 1)
-            ev.append(e2)
+            ev.append(dict(e, pid=pid))
     if extra:
         ev.append({"name": "process_name", "ph": "M", "ts": 0,
                    "pid": extra_pid, "tid": 0,

@@ -130,9 +130,9 @@ def test_trace_schema_and_request_spans(tmp_path):
         span = spans[f"req {uid}"]
         assert span["args"]["generated"] == len(r.tokens)
         assert span["args"]["finish"] == r.finish_reason
-    kinds = {e["name"] for e in trace["traceEvents"]
-             if e.get("tid") == tracing.STEP_TID and e["ph"] == "X"}
-    assert kinds <= {"plain", "mixed", "admit"} and kinds
+    lanes = {e["args"]["name"] for e in trace["traceEvents"]
+             if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert {"queue", "slot 0", "compiles"} <= lanes
     assert any(e["ph"] == "C" and e["name"] == "active_slots"
                for e in trace["traceEvents"])
 
