@@ -10,9 +10,7 @@ runs is compiled (or loaded from the persistent cache), then
 compile is an error. With ``trace`` the profiler starts ``TRACE_LEAD_S``
 before the window (its start-up then falls in the warm-up), covers the
 first ``TRACE_S`` seconds of the window, and the run reports per-layer
-metrics, read over the traced ticks: the engine's registry is read as
-the profiler stops, since stopping it holds the engine for many seconds
-inside the window.
+metrics.
 After the window: peak device memory, the engine is freed, and the
 served tokens are compared with the reference (``check.py``).
 """
@@ -130,8 +128,6 @@ class Ctx:
     ticks: List[drive.Tick]          # the traced ticks
     red: Optional[trace.Reduced]
     flops: Any = None
-    trace: Optional[Dict] = None     # trace.load's data
-    registry: Optional[Dict] = None  # engine registry after the traced ticks
 
 
 def _pct(vals, q) -> Optional[float]:
@@ -193,10 +189,6 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace_on: bool, *,
     peaks = device.peaks(dev["kind"]) if require_tpu else {}
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    # key cached programs with their ops' metadata too: an executable
-    # cached from a build without the named scopes the readers read
-    # (metadata only, so otherwise the same key) must not be reused
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     compiles = _CompileCounter()
 
     config, traffic = cell.config, cell.traffic
@@ -225,7 +217,6 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace_on: bool, *,
         if trace_on else None
     profiling = False
     traced: List[drive.Tick] = []
-    registry: Optional[Dict] = None
     if tdir is not None:
         drv.run_until(t_window - TRACE_LEAD_S)
         t = time.perf_counter()
@@ -237,11 +228,10 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace_on: bool, *,
     n_warm = len(drv.ticks)
 
     def after_tick(tick):
-        nonlocal profiling, registry
+        nonlocal profiling
         if profiling:
             traced.append(tick)
             if tick.end >= t_window + TRACE_S:
-                registry = eng.metrics.snapshot()
                 t = time.perf_counter()
                 jax.profiler.stop_trace()
                 profiling = False
@@ -252,7 +242,6 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace_on: bool, *,
     drv.run_until(t_window + seconds, after_tick)
     compiles.close()
     if profiling:
-        registry = eng.metrics.snapshot()
         jax.profiler.stop_trace()
     steady = eng.metrics.counter("steady_compiles").value
     if compiles.count or steady:
@@ -282,7 +271,7 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace_on: bool, *,
         red = trace.reduce(data, [t.steps for t in traced])
         ctx = Ctx(cell=cell, config=config, traffic=traffic, peaks=peaks,
                   window=w, ticks=traced, red=red,
-                  flops=cells.flops(config), trace=data, registry=registry)
+                  flops=cells.flops(config))
         for m in cell.per_layer:
             v = cells.reader(m["name"]).read(ctx)
             if v is not None:

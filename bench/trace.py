@@ -4,22 +4,13 @@ per-operation time and idle gaps named by what the host was doing.
 ``load`` turns the ``.xplane.pb`` file into plain data:
 
     {"device": {plane: [[name, start_ns, dur_ns], ...]},   # op events
-     "host": [[name, start_ns, dur_ns], ...],               # bench:* spans
-     "engine": [[name, start_ns, dur_ns], ...],             # engine:* spans
-     "scopes": {op name: scope path}}                       # device ops
+     "host": [[name, start_ns, dur_ns], ...]}               # bench:* spans
 
 taking, on each TPU plane, the events of its ``XLA Ops`` line, and on the
 host the benchmark's own ``bench:tick`` / ``bench:submit`` /
-``bench:wait`` annotations (``drive.py``) and the engine's ``engine:*``
-spans (``serving/tracing.span``). All are on the profiler's clock. An
-op's scope path is the ``tf_op`` stat of its event metadata on the TPU
-plane (``jit(mixed)/prefill/while/body/closed_call/ssm/dot_general:``:
-the program, the engine's and the model's ``jax.named_scope``s, the
-primitive). ``jax.profiler.ProfileData`` does not expose metadata stats,
-so ``_op_scopes`` reads them from the file's protobuf encoding itself.
-``reduce`` and the ``scope_s`` / ``idle_in_spans_s`` sums work on that
-data alone, so they are tested on a small recorded trace
-(``tests/data``) and on hand-built ones.
+``bench:wait`` annotations (``drive.py``). Both sides are on the
+profiler's clock. ``reduce`` works on that data alone, so it is tested on
+a small recorded trace (``tests/data``).
 """
 from __future__ import annotations
 
@@ -33,8 +24,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OP_LINE = "XLA Ops"
 HOST_PREFIX = "bench:"
-ENGINE_PREFIX = "engine:"
-SCOPE_STAT = "tf_op"
 TOP = 10
 
 
@@ -45,12 +34,9 @@ def load(trace_dir: str) -> Dict:
                       recursive=True)
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    with open(max(paths, key=os.path.getmtime), "rb") as f:
-        raw = f.read()
-    prof = jax.profiler.ProfileData.from_serialized_xspace(raw)
+    prof = jax.profiler.ProfileData.from_file(max(paths, key=os.path.getmtime))
     device: Dict[str, List] = {}
     host: List = []
-    engine: List = []
     for plane in prof.planes:
         if DEVICE_PLANE.match(plane.name):
             ops = device.setdefault(plane.name, [])
@@ -63,98 +49,8 @@ def load(trace_dir: str) -> Dict:
                 for e in line.events:
                     if e.name.startswith(HOST_PREFIX):
                         host.append([e.name, e.start_ns, e.duration_ns])
-                    elif e.name.startswith(ENGINE_PREFIX):
-                        engine.append([e.name, e.start_ns, e.duration_ns])
     host.sort(key=lambda e: e[1])
-    engine.sort(key=lambda e: e[1])
-    return {"device": device, "host": host, "engine": engine,
-            "scopes": _op_scopes(raw)}
-
-
-def _varint(buf: bytes, i: int) -> Tuple[int, int]:
-    x = shift = 0
-    while True:
-        b = buf[i]
-        i += 1
-        x |= (b & 0x7F) << shift
-        if b < 0x80:
-            return x, i
-        shift += 7
-
-
-def _fields(buf: bytes, lo: int, hi: int):
-    """``(field number, value)`` of each field of the protobuf message in
-    ``buf[lo:hi]``: an integer for a varint, ``(start, end)`` offsets for
-    a length-delimited field, None for a fixed-width one."""
-    i = lo
-    while i < hi:
-        key, i = _varint(buf, i)
-        kind = key & 7
-        if kind == 0:
-            v, i = _varint(buf, i)
-        elif kind == 2:
-            n, i = _varint(buf, i)
-            v, i = (i, i + n), i + n
-        elif kind in (1, 5):
-            v, i = None, i + (8 if kind == 1 else 4)
-        else:
-            raise ValueError(f"protobuf wire type {kind} at {i}")
-        yield key >> 3, v
-
-
-def _text(buf: bytes, span: Tuple[int, int]) -> str:
-    return buf[span[0]:span[1]].decode("utf-8", "replace")
-
-
-def _op_scopes(buf: bytes) -> Dict[str, str]:
-    """``{op event name: tf_op}`` over the TPU planes of a serialized
-    ``XSpace`` (``tsl/profiler/protobuf/xplane.proto``: XSpace.planes 1;
-    XPlane.name 2, event_metadata 4, stat_metadata 5, both maps of
-    key 1 / value 2; XEventMetadata.name 2, stats 5; XStat.metadata_id 1,
-    str_value 5, ref_value 7; XStatMetadata.id 1, name 2). A ``ref_value``
-    names an interned string: the ``stat_metadata`` entry of that id."""
-    out: Dict[str, str] = {}
-    for num, plane in _fields(buf, 0, len(buf)):
-        if num != 1:
-            continue
-        name, events, names = "", [], {}
-        for f, v in _fields(buf, *plane):
-            if f == 2:
-                name = _text(buf, v)
-                if not DEVICE_PLANE.match(name):
-                    break                   # a host plane: nothing to read
-            elif f in (4, 5):
-                for g, entry in _fields(buf, *v):
-                    if g != 2:
-                        continue
-                    if f == 4:
-                        events.append(entry)
-                        continue
-                    sid, sname = None, ""
-                    for h, w in _fields(buf, *entry):
-                        if h == 1:
-                            sid = w
-                        elif h == 2:
-                            sname = _text(buf, w)
-                    names[sid] = sname
-        if not DEVICE_PLANE.match(name):
-            continue
-        want = [k for k, n in names.items() if n == SCOPE_STAT]
-        for entry in events:
-            ev_name, path = "", None
-            for h, w in _fields(buf, *entry):
-                if h == 2:
-                    ev_name = _text(buf, w)
-                elif h == 5:
-                    stat = dict(_fields(buf, *w))
-                    if stat.get(1) in want:
-                        if 5 in stat:
-                            path = _text(buf, stat[5])
-                        elif 7 in stat:
-                            path = names.get(stat[7], "")
-            if path is not None:
-                out[ev_name] = path
-    return out
+    return {"device": device, "host": host}
 
 
 def _union(intervals: Sequence[Tuple[float, float]]) -> List[List[float]]:
@@ -171,25 +67,20 @@ def _overlap(a: Sequence[List[float]], lo: float, hi: float) -> float:
     return sum(max(0.0, min(e, hi) - max(s, lo)) for s, e in a)
 
 
-def _meet(a: Sequence, b: Sequence) -> List[Tuple[float, float]]:
-    """The intersection of two sorted lists of disjoint intervals."""
-    out = []
+def _intersect(a: Sequence, b: Sequence) -> float:
+    """Length of the intersection of two sorted lists of disjoint
+    intervals."""
     i = j = 0
+    total = 0.0
     while i < len(a) and j < len(b):
         lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
         if hi > lo:
-            out.append((lo, hi))
+            total += hi - lo
         if a[i][1] < b[j][1]:
             i += 1
         else:
             j += 1
-    return out
-
-
-def _intersect(a: Sequence, b: Sequence) -> float:
-    """Length of the intersection of two sorted lists of disjoint
-    intervals."""
-    return sum(hi - lo for lo, hi in _meet(a, b))
+    return total
 
 
 @dataclass
@@ -225,16 +116,14 @@ def short_name(name: str) -> str:
     return out
 
 
-def _self_times(evs: Sequence, lo: float, hi: float,
-                key=short_name) -> Dict[str, float]:
+def _self_times(evs: Sequence, lo: float, hi: float) -> Dict[str, float]:
     """Each op's device time in the window minus that of the ops nested in
-    it (a while loop's event spans its body's), summed by ``key`` of its
-    name (by short name unless told otherwise)."""
+    it (a while loop's event spans its body's), summed by short name."""
     out: Dict[str, float] = {}
     stack: List[list] = []          # [end, name, self time]
 
     def close(item):
-        n = key(item[1])
+        n = short_name(item[1])
         out[n] = out.get(n, 0.0) + item[2] * 1e-9
     clipped = [(max(s, lo), min(s + d, hi), name) for name, s, d in evs]
     for a, b, name in sorted((c for c in clipped if c[1] > c[0]),
@@ -249,23 +138,16 @@ def _self_times(evs: Sequence, lo: float, hi: float,
     return out
 
 
-def _traced_ticks(data: Dict, tick_steps: Sequence[int]
-                  ) -> Tuple[List[Tuple[float, float]], List[int]]:
-    """The traced ticks' spans and their steps. The profiler stops after
-    the last traced tick, so the last ``len(tick_steps)`` ``bench:tick``
-    spans of the trace are those ticks; earlier spans belong to the
-    warm-up the profiler started in."""
+def reduce(data: Dict, tick_steps: Sequence[int]) -> Optional[Reduced]:
+    """``tick_steps``: the steps each traced tick ran, in order. The
+    profiler stops after the last traced tick, so the last
+    ``len(tick_steps)`` ``bench:tick`` spans of the trace are those ticks;
+    earlier spans belong to the warm-up the profiler started in. The
+    window runs from the first traced tick's start to the last one's end.
+    Returns None when the trace holds no tick or no device op."""
     ticks = [(s, s + d) for n, s, d in data["host"] if n == "bench:tick"]
     ticks = ticks[-len(tick_steps):] if tick_steps else []
-    return ticks, list(tick_steps)[len(tick_steps) - len(ticks):]
-
-
-def reduce(data: Dict, tick_steps: Sequence[int]) -> Optional[Reduced]:
-    """``tick_steps``: the steps each traced tick ran, in order
-    (``_traced_ticks``). The window runs from the first traced tick's
-    start to the last one's end. Returns None when the trace holds no
-    tick or no device op."""
-    ticks, tick_steps = _traced_ticks(data, tick_steps)
+    tick_steps = list(tick_steps)[len(tick_steps) - len(ticks):]
     if not ticks or not data["device"]:
         return None
     lo, hi = ticks[0][0], ticks[-1][1]
@@ -313,51 +195,3 @@ def breakdown(red: Reduced) -> Dict[str, List]:
     ops = sorted(red.ops.items(), key=lambda kv: -kv[1])[:TOP]
     return {"device_ops": [[n, s] for n, s in ops],
             "idle_gaps": [[n, s] for n, s in red.gaps]}
-
-
-def in_scope(path: str, scope: str) -> bool:
-    """Whether an op's scope path passes through the named scope."""
-    return scope in path.rstrip(":").split("/")
-
-
-def scope_s(data: Dict, tick_steps: Sequence[int],
-            scope: str) -> Optional[float]:
-    """Device self time of the ops under ``scope`` inside the traced
-    ticks' window (``reduce``'s), mean over chips, in seconds; None when
-    the trace holds no tick, no device op, or no op under that scope
-    anywhere (a program without it)."""
-    ticks, _ = _traced_ticks(data, tick_steps)
-    scopes = data.get("scopes") or {}
-    if not ticks or not data["device"] or not any(
-            in_scope(p, scope) for p in scopes.values()):
-        return None
-    lo, hi = ticks[0][0], ticks[-1][1]
-    total = 0.0
-    for evs in data["device"].values():
-        for name, t in _self_times(evs, lo, hi, key=str).items():
-            if in_scope(scopes.get(name, ""), scope):
-                total += t
-    return total / len(data["device"])
-
-
-def idle_in_spans_s(data: Dict, tick_steps: Sequence[int],
-                    names: Sequence[str]) -> Optional[Tuple[float, int]]:
-    """Device idle time inside the traced ticks that ran a step and inside
-    the ``engine:*`` host spans named in ``names``, mean over chips, in
-    seconds, with the number of such ticks. None when the trace holds no
-    such tick, no engine span or no device op."""
-    ticks, steps = _traced_ticks(data, tick_steps)
-    working = [t for t, n in zip(ticks, steps) if n > 0]
-    spans = _union([(s, s + d) for n, s, d in data.get("engine", ())
-                    if n in names])
-    if not working or not data.get("engine") or not data["device"]:
-        return None
-    region = _meet(spans, working)
-    inside = sum(e - s for s, e in region)
-    lo, hi = ticks[0][0], ticks[-1][1]
-    idle = 0.0
-    for evs in data["device"].values():
-        busy = _union([(max(s, lo), min(s + d, hi)) for _, s, d in evs
-                       if min(s + d, hi) > max(s, lo)])
-        idle += inside - _intersect(busy, region)
-    return idle / len(data["device"]) * 1e-9, len(working)

@@ -51,7 +51,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from repro.serving import faults as faults_mod
-from repro.serving import telemetry, tracing
+from repro.serving import telemetry
 from repro.serving.engine import Engine
 from repro.serving.request import Request, Response
 
@@ -144,7 +144,6 @@ class Fleet:
 
         if replicas < 1:
             raise ValueError(f"fleet needs >= 1 replica, got {replicas}")
-        self._anchor = tracing.clock_anchor()     # fleet lane's clock
         self.model, self.params = model, params
         self.engine_kwargs = dict(engine_kwargs or {})
         self.engine_kwargs.pop("recorder", None)
@@ -202,7 +201,7 @@ class Fleet:
                       recorder=self.trace, **self.engine_kwargs)
 
     def _event(self, name: str, **args) -> None:
-        self._events.append({"ts": time.perf_counter(), "name": name,
+        self._events.append({"ts": time.time_ns(), "name": name,
                              "args": args})
 
     def replica(self, rid: int) -> Replica:
@@ -776,6 +775,7 @@ class Fleet:
         ``100 + rid``) plus a fleet lane (pid 99) of orchestration
         instants (health transitions, failovers, hedges, drains).
         Requires ``Fleet(..., trace=True)``."""
+        from repro.serving.tracing import merge_chrome_traces
         parts = []
         for r in self.replicas:
             exp = getattr(r.engine, "recorder", None)
@@ -788,9 +788,9 @@ class Fleet:
                                "trace=True)")
         fleet_events = [
             {"name": ev["name"], "ph": "i",
-             "ts": tracing.profiler_us(ev["ts"], self._anchor),
+             "ts": round(ev["ts"] * 1e-3, 1),
              "pid": 99, "tid": 0, "s": "t", "args": ev["args"]}
             for ev in self._events]
-        return tracing.merge_chrome_traces(
-            parts, extra=fleet_events, extra_label="fleet", extra_pid=99,
-            path=path)
+        return merge_chrome_traces(parts, extra=fleet_events,
+                                   extra_label="fleet", extra_pid=99,
+                                   path=path)

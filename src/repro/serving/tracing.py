@@ -26,16 +26,19 @@ device ops of the same run.
 
 One clock: the profiler stamps with the system clock (``time.time_ns``,
 nanoseconds since the epoch; an ``.xplane.pb`` stores its times as
-offsets from the session's ``profile_start_time``). The tracer reads
-``time.perf_counter`` for its events and converts them on export with
-an anchor pair read at construction (:func:`clock_anchor`), so its
-``ts`` are microseconds on the profiler's clock: every replica's export
-and a profile of the same run share one time base, and
-:func:`profile_trace` renders a profile on it for
-:func:`merge_chrome_traces`. The tracer is pure host bookkeeping: it
-never touches device state, so a traced run's greedy outputs and
-compiled-program counts are bit-identical to an untraced one (asserted
-in ``tests/test_telemetry.py``).
+offsets from the session's ``profile_start_time``). The tracer keeps
+its events on that clock: it stamps them with ``time.time_ns`` or, for
+the engine's ``time.perf_counter`` stamps, converts each as it arrives
+(:func:`profiler_ns`), so a step or slew of the system clock moves the
+tracer's later events as it moves the profiler's. Its ``ts`` are
+microseconds on the profiler's clock: every replica's export and a
+profile of the same run share one time base, and :func:`profile_trace`
+renders a profile on it for :func:`merge_chrome_traces`.
+
+The tracer is pure host bookkeeping: it never touches device state, so
+a traced run's greedy outputs and compiled-program counts are
+bit-identical to an untraced one (asserted in
+``tests/test_telemetry.py``).
 """
 from __future__ import annotations
 
@@ -49,7 +52,7 @@ import jax
 
 from repro.serving.telemetry import Recorder
 
-__all__ = ["Tracer", "span", "clock_anchor", "profiler_us",
+__all__ = ["Tracer", "span", "profiler_ns", "newest_profile",
            "profile_trace", "validate_chrome_trace", "complete_spans",
            "merge_chrome_traces"]
 
@@ -68,35 +71,34 @@ def span(phase: str) -> jax.profiler.TraceAnnotation:
     return jax.profiler.TraceAnnotation(SPAN_PREFIX + phase)
 
 
-def clock_anchor() -> Tuple[float, int]:
-    """``(time.perf_counter(), profiler clock in ns)``, read back to
-    back."""
-    return time.perf_counter(), time.time_ns()
+def profiler_ns(t: float) -> int:
+    """A ``time.perf_counter`` stamp as nanoseconds on the profiler's
+    clock, through a reading of both clocks taken now: convert a stamp
+    soon after it is taken."""
+    return time.time_ns() - round((time.perf_counter() - t) * 1e9)
 
 
-def profiler_us(t: float, anchor: Tuple[float, int]) -> float:
-    """A ``time.perf_counter`` stamp as microseconds on the profiler's
-    clock, through ``anchor`` (:func:`clock_anchor`)."""
-    pc, ns = anchor
-    return round((ns + (t - pc) * 1e9) * 1e-3, 1)
+def _us(ns: int) -> float:
+    return round(ns * 1e-3, 1)
 
 
 class Tracer(Recorder):
     enabled = True
 
     def __init__(self):
-        self.anchor = clock_anchor()
+        self.t0 = time.time_ns()
         # uid -> lifecycle record (insertion order = submit order)
         self.requests: Dict[int, Dict[str, Any]] = {}
-        self.polls: List[Tuple[float, int, Dict[str, float]]] = []
-        self.compiles: List[Tuple[float, str, float, bool]] = []
-        self.faults: List[Tuple[float, str, int]] = []
+        # stamps in ns on the profiler's clock
+        self.polls: List[Tuple[int, int, Dict[str, float]]] = []
+        self.compiles: List[Tuple[int, str, float, bool]] = []
+        self.faults: List[Tuple[int, str, int]] = []
 
     # -- Recorder hooks ------------------------------------------------ #
     def on_submit(self, req) -> None:
         self.requests[req.uid] = {
             "uid": req.uid, "prompt_len": len(req.prompt),
-            "submitted": time.perf_counter(), "admitted": None,
+            "submitted": time.time_ns(), "admitted": None,
             "slot": None, "kind": "", "base": 0, "chunks": [],
             "first_token": None, "emits": [], "finished": None,
             "reason": "", "generated": 0, "preempts": []}
@@ -105,7 +107,7 @@ class Tracer(Recorder):
         r = self.requests.get(req.uid)
         if r is None:
             return
-        r["admitted"] = time.perf_counter()
+        r["admitted"] = time.time_ns()
         r["slot"] = slot
         r["kind"] = kind
         r["base"] = base
@@ -114,52 +116,49 @@ class Tracer(Recorder):
                  last: bool) -> None:
         r = self.requests.get(req.uid)
         if r is not None:
-            r["chunks"].append((time.perf_counter(), lo, hi, last))
+            r["chunks"].append((time.time_ns(), lo, hi, last))
 
     def on_first_token(self, req, ts: float) -> None:
         r = self.requests.get(req.uid)
         if r is not None and r["first_token"] is None:
-            r["first_token"] = ts
+            r["first_token"] = profiler_ns(ts)
 
     def on_emit(self, req, slot: int, n: int, ts: float) -> None:
         r = self.requests.get(req.uid)
         if r is not None and n:
-            r["emits"].append((ts, n))
+            r["emits"].append((profiler_ns(ts), n))
             r["generated"] += n
 
     def on_finish(self, req, reason: str, ts: float) -> None:
         r = self.requests.get(req.uid)
         if r is not None:
-            r["finished"] = ts
+            r["finished"] = profiler_ns(ts)
             r["reason"] = reason
 
     def on_preempt(self, req, slot: int, ts: float) -> None:
         r = self.requests.get(req.uid)
         if r is not None:
-            r["preempts"].append((ts, slot))
+            r["preempts"].append((profiler_ns(ts), slot))
 
     def on_fault(self, site: str, step: int, ts: float) -> None:
-        self.faults.append((ts, site, step))
+        self.faults.append((profiler_ns(ts), site, step))
 
     def on_poll(self, ts: float, active: int,
                 stats: Dict[str, float]) -> None:
-        self.polls.append((ts, active, dict(stats)))
+        self.polls.append((profiler_ns(ts), active, dict(stats)))
 
     def on_compile(self, name: str, elapsed_s: float, steady: bool,
                    ts: float) -> None:
-        self.compiles.append((ts, name, elapsed_s, steady))
+        self.compiles.append((profiler_ns(ts), name, elapsed_s, steady))
 
     # -- export -------------------------------------------------------- #
-    def _us(self, t: float) -> float:
-        return profiler_us(t, self.anchor)
-
     def export_chrome_trace(self, path: Optional[str] = None
                             ) -> Dict[str, Any]:
         """Render the collected run as a Chrome trace-event object
         (``{"traceEvents": [...]}``); write JSON to ``path`` when given.
         Requests still running (or never admitted) at export time get an
         open-ended span cut at "now" with reason ``evicted``."""
-        now = time.perf_counter()
+        now = time.time_ns()
         ev: List[Dict[str, Any]] = []
 
         def meta(tid: int, name: str) -> None:
@@ -185,10 +184,10 @@ class Tracer(Recorder):
             end = r["finished"] if r["finished"] is not None else now
             # queue lane: submit -> admission (or still waiting)
             ev.append({"name": f"queue u{uid}", "ph": "X",
-                       "ts": self._us(r["submitted"]),
-                       "dur": max(0.0, round(
-                           ((adm if adm is not None else end)
-                            - r["submitted"]) * 1e6, 1)),
+                       "ts": _us(r["submitted"]),
+                       "dur": max(0.0, _us(
+                           (adm if adm is not None else end)
+                           - r["submitted"])),
                        "pid": _PID, "tid": QUEUE_TID,
                        "args": {"uid": uid,
                                 "prompt_len": r["prompt_len"]}})
@@ -197,8 +196,8 @@ class Tracer(Recorder):
             tid = 1 + r["slot"]
             # the request's complete span on its slot lane
             ev.append({"name": f"req {uid}", "ph": "X",
-                       "ts": self._us(adm),
-                       "dur": max(0.0, round((end - adm) * 1e6, 1)),
+                       "ts": _us(adm),
+                       "dur": max(0.0, _us(end - adm)),
                        "pid": _PID, "tid": tid,
                        "args": {"uid": uid,
                                 "prompt_len": r["prompt_len"],
@@ -209,45 +208,45 @@ class Tracer(Recorder):
                                 "finish": r["reason"] or "evicted"}})
             for (t, pslot) in r["preempts"]:
                 ev.append({"name": "preempt", "ph": "i",
-                           "ts": self._us(t), "pid": _PID,
+                           "ts": _us(t), "pid": _PID,
                            "tid": 1 + pslot, "s": "t",
                            "args": {"uid": uid}})
             for (t, lo, hi, last) in r["chunks"]:
                 ev.append({"name": f"chunk {lo}:{hi}", "ph": "i",
-                           "ts": self._us(t), "pid": _PID, "tid": tid,
+                           "ts": _us(t), "pid": _PID, "tid": tid,
                            "s": "t",
                            "args": {"uid": uid, "last": bool(last)}})
             if r["first_token"] is not None:
                 ev.append({"name": "first_token", "ph": "i",
-                           "ts": self._us(r["first_token"]),
+                           "ts": _us(r["first_token"]),
                            "pid": _PID, "tid": tid, "s": "t",
                            "args": {"uid": uid}})
             for (t, n) in r["emits"]:
                 ev.append({"name": f"emit {n}", "ph": "i",
-                           "ts": self._us(t), "pid": _PID, "tid": tid,
+                           "ts": _us(t), "pid": _PID, "tid": tid,
                            "s": "t", "args": {"uid": uid, "n": n}})
             if r["finished"] is not None:
                 ev.append({"name": f"finish:{r['reason']}", "ph": "i",
-                           "ts": self._us(r["finished"]), "pid": _PID,
+                           "ts": _us(r["finished"]), "pid": _PID,
                            "tid": tid, "s": "t", "args": {"uid": uid}})
 
         for (t, site, step) in self.faults:
             ev.append({"name": f"fault {site}", "ph": "i",
-                       "ts": self._us(t), "pid": _PID, "tid": FAULT_TID,
+                       "ts": _us(t), "pid": _PID, "tid": FAULT_TID,
                        "s": "t", "args": {"site": site, "step": step}})
         for (t, name, elapsed, steady) in self.compiles:
             ev.append({"name": f"compile {name}", "ph": "X",
-                       "ts": self._us(max(t, self.anchor[0])),
+                       "ts": _us(max(t, self.t0)),
                        "dur": round(elapsed * 1e6, 1),
                        "pid": _PID, "tid": COMPILE_TID,
                        "args": {"steady": bool(steady)}})
         for (t, active, stats) in self.polls:
             ev.append({"name": "active_slots", "ph": "C",
-                       "ts": self._us(t), "pid": _PID,
+                       "ts": _us(t), "pid": _PID,
                        "args": {"active": active}})
             if "kv_pages_live" in stats:
                 ev.append({"name": "page_pool", "ph": "C",
-                           "ts": self._us(t), "pid": _PID,
+                           "ts": _us(t), "pid": _PID,
                            "args": {"live": stats["kv_pages_live"],
                                     "free": stats["kv_pages_free"]}})
 
@@ -261,6 +260,16 @@ class Tracer(Recorder):
 # --------------------------------------------------------------------- #
 # a profile on the same clock, and the multi-process merge
 # --------------------------------------------------------------------- #
+def newest_profile(trace_dir: str) -> "jax.profiler.ProfileData":
+    """The newest ``.xplane.pb`` under ``trace_dir``, loaded."""
+    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    return jax.profiler.ProfileData.from_file(
+        max(paths, key=os.path.getmtime))
+
+
 def profile_trace(trace_dir: str) -> Dict[str, Any]:
     """The newest ``jax.profiler`` session under ``trace_dir`` (an
     engine's ``trace_dir=`` / ``serve.py --trace-dir``) as a Chrome
@@ -268,12 +277,7 @@ def profile_trace(trace_dir: str) -> Dict[str, Any]:
     :class:`Tracer` export: one lane per line of each plane (host
     threads with their ``engine:*`` spans, each device's XLA ops and
     modules), thread names ``<plane> <line>``."""
-    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    prof = jax.profiler.ProfileData.from_file(
-        max(paths, key=os.path.getmtime))
+    prof = newest_profile(trace_dir)
     origin = None
     for plane in prof.planes:
         for key, value in plane.stats:

@@ -2,8 +2,6 @@
 the phases of ``Engine.tick``, the clock the Tracer's export shares with
 the profiler, the named scopes in the compiled programs, and the
 ``queue_wait_s`` histogram."""
-import glob
-import os
 import re
 import time
 
@@ -39,13 +37,6 @@ def _request(uid, n=6, max_new=12, **kw):
                    max_new_tokens=max_new, **kw)
 
 
-def _profile(trace_dir):
-    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    return jax.profiler.ProfileData.from_file(
-        max(paths, key=os.path.getmtime))
-
-
 def _host_spans(prof, prefix):
     """``[(name, start_ns, end_ns)]`` of the host events whose name
     starts with ``prefix``, in start order (offsets from the session's
@@ -69,7 +60,7 @@ def _traced_tick(eng, trace_dir):
             steps = eng.tick()
     finally:
         jax.profiler.stop_trace()
-    return steps, _profile(str(trace_dir))
+    return steps, tracing.newest_profile(str(trace_dir))
 
 
 def test_tick_spans_in_order_inside_callers_span(tmp_path):
@@ -93,28 +84,46 @@ def test_tick_spans_in_order_inside_callers_span(tmp_path):
 
 
 def test_tracer_stamps_on_the_profilers_clock(tmp_path):
-    """A ``perf_counter`` stamp taken inside a span, converted by the
-    Tracer's anchor, falls inside that span as the ``.xplane.pb`` holds
-    it: the profiler stamps with the system clock (``time.time_ns``),
-    its times counted from ``profile_start_time``."""
+    """An event the Tracer records inside a span, from a
+    ``perf_counter`` stamp as the engine hands it over, falls inside that
+    span in its export as the ``.xplane.pb`` holds it: the profiler
+    stamps with the system clock (``time.time_ns``), its times counted
+    from ``profile_start_time``."""
     tracer = tracing.Tracer()
     jax.profiler.start_trace(str(tmp_path))
-    stamps = []
     for i in range(3):
         with tracing.span(f"clock{i}"):
             time.sleep(0.002)
-            stamps.append(time.perf_counter())
+            tracer.on_fault(f"clock{i}", i, time.perf_counter())
             time.sleep(0.002)
     jax.profiler.stop_trace()
-    prof = _profile(str(tmp_path))
+    prof = tracing.newest_profile(str(tmp_path))
     origin = _profile_start_ns(prof)
     spans = _host_spans(prof, "engine:clock")
-    assert len(spans) == 3
-    for t, (_, s, e) in zip(stamps, spans):
-        ns = 1e3 * tracer._us(t) - origin
+    faults = [e for e in tracer.export_chrome_trace()["traceEvents"]
+              if e["name"].startswith("fault clock")]
+    assert len(spans) == len(faults) == 3
+    for f, (_, s, e) in zip(faults, spans):
+        ns = 1e3 * f["ts"] - origin
         assert s < ns < e
         # well inside: the two clocks agree to far better than the 2 ms
         assert min(ns - s, e - ns) > 1e6
+
+
+def test_tracer_follows_a_step_of_the_system_clock(monkeypatch):
+    """The Tracer converts each stamp as it arrives, so a step of the
+    system clock (as NTP makes in a long-running server) moves the
+    events recorded after it, as it moves the profiler's stamps, and not
+    those recorded before it."""
+    tracer = tracing.Tracer()
+    tracer.on_fault("before", 0, time.perf_counter())
+    system_ns = time.time_ns
+    monkeypatch.setattr(tracing.time, "time_ns",
+                        lambda: system_ns() + 10**9)
+    tracer.on_fault("after", 1, time.perf_counter())
+    ts = {e["name"]: e["ts"] for e in tracer.export_chrome_trace()[
+        "traceEvents"] if e["name"].startswith("fault ")}
+    assert 1e6 <= ts["fault after"] - ts["fault before"] < 1.1e6
 
 
 def test_tracer_export_and_profile_line_up(tmp_path):
