@@ -16,9 +16,10 @@ Default run, on one chip:
    forward and ``prefill`` give first-position logits that are finite and
    agree within ``LOGIT_TOL``;
 3. mamba2-780m (48 layers, d_model 1536), the same checks. Its admission
-   runs the ``ssd_extend`` Pallas kernel and its prefill the ``ssd``
+   runs the ``ssd_extend`` Pallas kernels and its prefill the ``ssd``
    kernel; both are also compared with the jnp reference at these widths
-   within ``KERNEL_TOL``.
+   within ``KERNEL_TOL``, ``ssd_extend`` in its chunked form and in its
+   token-serial one.
 
 ``--four-chips`` runs only the tensor-parallel phase: llama3.2-1b served
 with ``--mesh 1,4`` and the same requests on one device, in this
@@ -66,6 +67,7 @@ TP_LOGIT_TOL = 0.05
 # Pallas SSD kernels vs the float32 jnp reference at mamba2-780m widths,
 # relative to max |reference|
 KERNEL_TOL = 2e-2
+SERIAL_T = 8        # ssd_extend tokens below the chunked form's block
 FORCE_ENVS = ("REPRO_FORCE_REF", "REPRO_FORCE_PALLAS")
 _T0 = time.perf_counter()
 
@@ -226,7 +228,10 @@ def oracle_phase(arch, responses):
 
 def ssd_kernel_phase(cfg):
     """ssd / ssd_extend compiled Pallas vs the jnp reference (float32
-    matmuls) at the config's widths."""
+    matmuls) at the config's widths, each case named by the Pallas call
+    its program must hold. ``ssd_extend`` is checked in both of its
+    forms: at ``L`` tokens (a multiple of the block) it runs the chunked
+    dual form, at ``SERIAL_T`` the token-serial kernel."""
     from repro.kernels.ssd_scan import ops as ssd_ops
     log("SSD kernels vs reference")
     s = cfg.ssm
@@ -240,12 +245,17 @@ def ssd_kernel_phase(cfg):
     C = jax.random.normal(ks[3], (1, L, g, n), jnp.float32)
     D = jnp.ones((h,), jnp.float32)
     s0 = 0.1 * jax.random.normal(ks[4], (1, h, p, n), jnp.float32)
+    head = lambda a: a[:, :SERIAL_T]
     cases = {
-        "ssd": (functools.partial(ssd_ops.ssd, chunk=s.chunk),
-                (x, dt, A, B, C, D)),
-        "ssd_extend": (ssd_ops.ssd_extend, (s0, x, dt, A, B, C, D)),
+        ("ssd_scan", L): (functools.partial(ssd_ops.ssd, chunk=s.chunk),
+                          (x, dt, A, B, C, D)),
+        ("ssd_extend_chunked", L): (ssd_ops.ssd_extend,
+                                    (s0, x, dt, A, B, C, D)),
+        ("ssd_extend", SERIAL_T): (
+            ssd_ops.ssd_extend,
+            (s0, head(x), head(dt), A, head(B), head(C), D)),
     }
-    for name, (fn, args) in cases.items():
+    for (name, T), (fn, args) in cases.items():
         pallas = jax.jit(functools.partial(fn, use_pallas=True))
         y, st = pallas(*args)
         with jax.default_matmul_precision("highest"):
@@ -253,11 +263,13 @@ def ssd_kernel_phase(cfg):
         ey, _ = _rel_err(y, yr)
         es, _ = _rel_err(st, sr)
         print(f"{name} Pallas vs jnp reference at "
-              f"L={L} h={h} p={p} n={n}: y {ey:.6g}, state {es:.6g} "
+              f"L={T} h={h} p={p} n={n}: y {ey:.6g}, state {es:.6g} "
               f"(max|diff|/max|ref|, tol {KERNEL_TOL})")
         check(max(ey, es) <= KERNEL_TOL, f"{name}: Pallas vs reference")
-        check("tpu_custom_call" in pallas.lower(*args).compile().as_text(),
+        hlo = pallas.lower(*args).compile().as_text()
+        check("tpu_custom_call" in hlo,
               f"{name}: no compiled Pallas kernel in the program")
+        check(f"%{name}." in hlo, f"{name}: the program runs another form")
 
 
 def four_chip_phase():

@@ -38,13 +38,31 @@ def ssd(x, dt, A, B, C, D=None, *, chunk=64, initial_state=None,
                               initial_state=initial_state)
 
 
+# Tokens per block of the chunked extend (timed on a TPU v5e at the
+# mamba2-780m admission chunk against 256, PERF.md).
+EXTEND_CHUNK = 128
+
+
 def ssd_extend(state, x, dt, A, B, C, D=None, *, use_pallas=None):
-    """Multi-token sequential recurrence from an explicit state — the
-    serving engine's chunked-admission / speculative-verify form.
-    Bitwise equal to T applications of ``ssd_step`` on both paths."""
+    """Multi-token recurrence from an explicit state — the serving
+    engine's chunked-admission / speculative-verify form.
+
+    The jnp reference is token-serial: bitwise equal to T applications
+    of ``ssd_step``. The Pallas path picks its form from T: a whole
+    multiple of ``EXTEND_CHUNK`` tokens (an admission or replay chunk)
+    runs the chunked dual form on the MXU from the carried state, which
+    agrees with the serial recurrence numerically, not bitwise; any
+    other T (decode rows, verify windows) runs the token-serial kernel,
+    bitwise equal to the serial steps. Both keep a row whose ``dt`` is
+    all zero exactly as it was."""
     use, interpret = dispatch.resolve(use_pallas)
     if use:
         from repro.kernels.ssd_scan import kernel as _k
+        T = x.shape[1]
+        if T >= EXTEND_CHUNK and T % EXTEND_CHUNK == 0:
+            return _k.ssd_extend_chunked_pallas(
+                state, x, dt, A, B, C, D, chunk=EXTEND_CHUNK,
+                interpret=interpret)
         return _k.ssd_extend_pallas(state, x, dt, A, B, C, D,
                                     interpret=interpret)
     return _ref.ssd_extend_reference(state, x, dt, A, B, C, D)

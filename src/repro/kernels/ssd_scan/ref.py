@@ -89,11 +89,11 @@ def ssd_extend_reference(state, x, dt, A, B, C, D=None):
 
     Exactly T applications of ``ssd_decode_step`` — bitwise, not just
     numerically: extending by [t1, t2] chunks equals extending by
-    [t1 + t2] equals t1+t2 single decode steps. This per-token
-    compositionality is the invariant the serving engine's chunked
-    admission relies on for SSM stacks (the chunked dual form in
-    ``ssd_reference``/``ssd_pallas`` is faster for long prefills but its
-    float reduction order changes with the chunking).
+    [t1 + t2] equals t1+t2 single decode steps. It stays token-serial as
+    the oracle of the Pallas forms. The chunked dual form (``ssd_pallas``,
+    and ``ssd_extend`` on the chip for chunks of whole blocks) is faster
+    but agrees with it only numerically: its float reduction order
+    changes with the chunking.
     """
     xs = (jnp.moveaxis(x, 1, 0), jnp.moveaxis(dt, 1, 0),
           jnp.moveaxis(B, 1, 0), jnp.moveaxis(C, 1, 0))

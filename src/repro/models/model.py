@@ -46,7 +46,9 @@ class Model:
       T). Speculative verify, chunked prefill and the serving engine's
       fused mixed (decode + prefill-chunk) step all share this one code
       path. Attention rings use the masked scatter, SSM mixers the
-      sequential ``ssd_extend`` recurrence, encdec the decoder ring with
+      ``ssd_extend`` recurrence from the cached state (token-serial for
+      decode rows and verify windows, the chunked dual form for
+      admission chunks on the chip), encdec the decoder ring with
       prefill-frozen cross-attention memory.
     * ``verify_step(params, tokens, cache)`` is extend with the full
       window (every row advances by T) — the speculative-decoding verify

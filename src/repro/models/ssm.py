@@ -88,11 +88,14 @@ def ssm_block(p, u, cfg: ModelConfig, *, cache=None, return_cache=False,
     ``mode="extend"`` -> multi-token cached recurrence at per-row
     offsets (the serving engine's chunked admission / speculative
     verify): every row advances by ``length[b] <= L`` tokens through
-    the sequential ``ssd_extend`` form, masked positions are exact
-    identity steps (dt = 0 -> decay 1, zero input) and the conv tail is
-    gathered from the last valid inputs, so a length-0 row's cache is
-    bit-untouched and chunked extends compose bitwise with a single
-    whole-prompt extend. Returns (y, new_cache).
+    ``ssd_extend``, masked positions are exact identity steps (dt = 0 ->
+    decay 1, zero input) and the conv tail is gathered from the last
+    valid inputs, so a length-0 row's cache is bit-untouched. On the
+    token-serial form (the jnp reference; the Pallas kernel for L not a
+    whole number of its blocks) chunked extends compose bitwise with a
+    single whole-prompt extend; on the chunked form (Pallas, L a whole
+    number of blocks, as the engine's admission chunks are) they agree
+    numerically. Returns (y, new_cache).
 
     ``length``: optional (B,) int32 valid-token count when ``u`` is
     right-padded (bucketed prefill). Padded positions get ``dt = 0`` —

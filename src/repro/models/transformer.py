@@ -178,9 +178,9 @@ def apply_block(bp, x, cfg: ModelConfig, *, mode: str, cache=None,
     <= T tokens, None = all rows advance by T). 'extend' is the masked
     multi-token cached decode shared by speculative verify, chunked
     prefill and the engine's fused mixed step; every mixer supports it
-    (attention via the masked ring scatter, SSM via the sequential
-    ``ssd_extend`` recurrence with identity steps past each row's
-    length). MoE FFNs route *densely* (per-token, capacity-free) in the
+    (attention via the masked ring scatter, SSM via the ``ssd_extend``
+    recurrence from the cached state, with identity steps past each
+    row's length; see ``ssm.ssm_block`` for its two forms). MoE FFNs route *densely* (per-token, capacity-free) in the
     cached serving modes so chunk padding and batch composition cannot
     distort expert assignment — see ``moe.moe_block``."""
     spec = block_spec(cfg)

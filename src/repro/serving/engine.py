@@ -43,8 +43,9 @@ through the block table via the paged attention path in
 ``models/layers.py``.
 
 Chunked admission is the ONLY admission path: every family — dense,
-MoE (dense routing in cached modes), SSM (sequential ``ssd_extend``
-recurrence), hybrid, VLM (the frontend prefix enters as one embedding
+MoE (dense routing in cached modes), SSM (``ssd_extend`` from the
+cached state, in the chunked dual form for whole-block chunks on the
+chip), hybrid, VLM (the frontend prefix enters as one embedding
 chunk) and encoder–decoder (cross-attention memory encoded once at
 admission, decoder ring chunked like any other) — flows through
 ``Model.extend_into_cache``. The v2 monolithic slot-direct prefill is

@@ -255,6 +255,67 @@ def test_ssd_extend_kernel(b, T, h, p, g, n):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("b,blocks,g,tail", [
+    (1, 1, 1, "full"), (2, 2, 2, "full"), (1, 4, 1, "full"),
+    (2, 2, 1, "dt0_tail"), (1, 4, 2, "dt0_tail"),
+    (2, 1, 2, "length0"), (2, 4, 1, "length0"),
+])
+def test_ssd_extend_chunked_kernel(b, blocks, g, tail):
+    """The chunked dual form from a carried state, T = blocks x the
+    dispatch's block, against the token-serial reference at the serial
+    kernel's tolerances. ``dt0_tail``: row 0 stops 37 tokens into its
+    last block (the engine's mask, dt = 0 past a row's length);
+    ``length0``: the last row has no valid token and must keep its state
+    bit for bit."""
+    from repro.kernels.ssd_scan.kernel import ssd_extend_chunked_pallas
+    from repro.kernels.ssd_scan.ops import EXTEND_CHUNK as Q
+    from repro.kernels.ssd_scan.ref import ssd_extend_reference
+    T, h, p, n = blocks * Q, 4, 32, 64
+    state = _arr((b, h, p, n))
+    x = _arr((b, T, h, p))
+    dt = rng.uniform(1e-3, 0.1, (b, T, h))
+    if tail == "dt0_tail":
+        dt[0, T - Q + 37:] = 0.0
+    if tail == "length0":
+        dt[-1] = 0.0
+    dt = jnp.asarray(dt, jnp.float32)
+    A = jnp.asarray(-rng.uniform(0.5, 2.0, (h,)), jnp.float32)
+    Bm = _arr((b, T, g, n))
+    Cm = _arr((b, T, g, n))
+    D = _arr((h,))
+    y, s = ssd_extend_chunked_pallas(state, x, dt, A, Bm, Cm, D, chunk=Q,
+                                     interpret=True)
+    yr, sr = ssd_extend_reference(state, x, dt, A, Bm, Cm, D)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(sr),
+                               rtol=1e-5, atol=1e-5)
+    if tail == "length0":
+        np.testing.assert_array_equal(np.asarray(s[-1]),
+                                      np.asarray(state[-1]))
+
+
+@pytest.mark.parametrize("T,form", [
+    (1, "ssd_extend"),             # decode rows of a mixed step
+    (5, "ssd_extend"),             # a verify window, gamma 4
+    (None, "ssd_extend"),          # a block and a bit: not a multiple
+    (512, "ssd_extend_chunked"),   # the admission chunk
+])
+def test_ssd_extend_dispatch_by_length(T, form):
+    """``ops.ssd_extend`` picks its Pallas form from T alone, as the
+    names of the Pallas calls in its traced program show."""
+    from repro.kernels.ssd_scan import ops as ssd_ops
+    T = T or ssd_ops.EXTEND_CHUNK + 8
+    b, h, p, g, n = 2, 2, 16, 1, 32
+    args = (jnp.zeros((b, h, p, n)), jnp.zeros((b, T, h, p)),
+            jnp.zeros((b, T, h)), jnp.zeros((h,)), jnp.zeros((b, T, g, n)),
+            jnp.zeros((b, T, g, n)), jnp.zeros((h,)))
+    fn = lambda *a: ssd_ops.ssd_extend(*a, use_pallas=True)
+    calls = [eqn.params["name"] for eqn in jax.make_jaxpr(fn)(*args).eqns
+             if eqn.primitive.name == "pallas_call"]
+    assert calls == [form]
+
+
 def test_ssd_decode_step_matches_scan():
     """Recurrent decode steps reproduce the chunked scan outputs."""
     from repro.kernels.ssd_scan.ref import ssd_decode_step, ssd_reference

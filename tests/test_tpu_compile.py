@@ -12,6 +12,7 @@ The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library at a time, and the file's tests all
 run in the process that describes it.
 """
+import functools
 import os
 
 import jax
@@ -25,7 +26,9 @@ from repro.kernels.flash_attention.kernel import flash_attention_gqa_pallas
 from repro.kernels.quant_matmul.kernel import (quant_matmul_int4_pallas,
                                                quant_matmul_int8_pallas)
 from repro.kernels.rmsnorm.kernel import fused_rmsnorm_pallas
-from repro.kernels.ssd_scan.kernel import ssd_extend_pallas, ssd_pallas
+from repro.kernels.ssd_scan.kernel import (ssd_extend_chunked_pallas,
+                                           ssd_extend_pallas, ssd_pallas)
+from repro.kernels.ssd_scan.ops import EXTEND_CHUNK
 
 f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
 # mamba2-780m: d_inner 3072 = 48 heads x 64, d_state 128, 1 group, chunk 256
@@ -64,6 +67,14 @@ def no_compile_cache():
     cc.reset_cache()
 
 
+def _ssd_extend_chunk_shapes():
+    """(state, x, dt, A, B, C, D) of the mamba2-780m admission chunk."""
+    h, p, n, T = SSD["h"], SSD["p"], SSD["n"], 512
+    return [((1, h, p, n), f32), ((1, T, h, p), f32), ((1, T, h), f32),
+            ((h,), f32), ((1, T, 1, n), f32), ((1, T, 1, n), f32),
+            ((h,), f32)]
+
+
 def _cases():
     h, p, n, chunk = SSD["h"], SSD["p"], SSD["n"], SSD["chunk"]
     B, Hq, Hkv, hd, S = (ATTN[k] for k in ("B", "Hq", "Hkv", "hd", "S"))
@@ -75,12 +86,16 @@ def _cases():
                 [((1, 512, h, p), bf16), ((1, 512, h), f32), ((h,), f32),
                  ((1, 512, 1, n), bf16), ((1, 512, 1, n), bf16),
                  ((h,), f32)]),
-        # chunked admission: one 1024-token chunk from a carried state
+        # the serial form: one 1024-token chunk from a carried state
         "ssd_extend": (ssd_extend_pallas,
                        [((1, h, p, n), f32), ((1, 1024, h, p), f32),
                         ((1, 1024, h), f32), ((h,), f32),
                         ((1, 1024, 1, n), f32), ((1, 1024, 1, n), f32),
                         ((h,), f32)]),
+        # chunked admission: the 512-token chunk in the chunked form
+        "ssd_extend_chunked": (
+            functools.partial(ssd_extend_chunked_pallas, chunk=EXTEND_CHUNK),
+            _ssd_extend_chunk_shapes()),
         "decode_attention": (decode_attention_pallas,
                              [((B, Hq, hd), bf16), ((B, Hkv, S, hd), bf16),
                               ((B, Hkv, S, hd), bf16), ((B, S), i32),
@@ -112,3 +127,23 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_extend_chunked_is_read_by_the_roofline(one_chip,
+                                                    no_compile_cache):
+    """The benchmark's ``ssd_extend`` yardstick reads the compiled
+    chunked call, from the instruction text a profiler event carries
+    (operand shapes printed), as the chat cell's admission chunk."""
+    from jax._src.lib import xla_client
+    from bench.kernels import ssd_extend as yardstick
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in _ssd_extend_chunk_shapes()]
+    fn = functools.partial(ssd_extend_chunked_pallas, chunk=EXTEND_CHUNK)
+    compiled = jax.jit(fn).lower(*args).compile()
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and calls[0].startswith("%ssd_extend_chunked.")
+    assert yardstick.shapes(calls[0]) == (1, 512, 48, 64, 1, 128)
